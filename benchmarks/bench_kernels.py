@@ -270,6 +270,9 @@ if __name__ == "__main__":
     import sys
 
     from repro.study import claims
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
 
     profile = sys.argv[1] if len(sys.argv) > 1 else "ci"
     rows = run(profile)

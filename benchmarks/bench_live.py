@@ -343,6 +343,9 @@ if __name__ == "__main__":
     import sys
 
     from repro.study import claims
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("profile", nargs="?", default="ci",

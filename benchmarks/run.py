@@ -40,6 +40,7 @@ from benchmarks import (bench_kernels, bench_live, bench_serve, common,
 from repro.obs import trace
 from repro.study import claims
 from repro.study.store import StudyStore
+from repro.utils import compile_cache
 
 MODULES = {
     "table4_sync": table4_sync,
@@ -86,13 +87,17 @@ def main(argv=None):
 
     if args.workers < 1:
         ap.error(f"--workers must be >= 1: {args.workers}")
+    compile_cache.enable()
     if args.real:
         common.set_source("real")
     if args.workers > 1:
         from repro.sweep import LocalProcessExecutor
-        common.RUNNER.executor = LocalProcessExecutor(
-            workers=args.workers,
-            work_dir=common.RESULTS_DIR / "sweep_workers")
+        try:
+            common.RUNNER.executor = LocalProcessExecutor(
+                workers=args.workers,
+                work_dir=common.RESULTS_DIR / "sweep_workers")
+        except ValueError as e:     # e.g. a TPU host: one process, one chip
+            ap.error(str(e))
 
     selected = list(MODULES)
     if args.only:

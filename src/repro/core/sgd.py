@@ -219,6 +219,19 @@ class RunResult:
         return float(np.mean(self.epoch_times))
 
 
+def _bind(fn, data):
+    """``fn(data, *args)`` jitted, with ``data`` bound as an argument.
+
+    A jitted function that closes over arrays compiles them into the
+    program as constants: at covtype's full size that is a 350 MB
+    executable per program, seconds of TPU compile each, and too large
+    for the persistent compile cache.  The result is a pytree
+    (``jax.tree_util.Partial``), so a caller that jits over it, like the
+    study runner's stacked vmap, receives ``data`` as an argument too.
+    """
+    return jax.tree_util.Partial(jax.jit(fn), data)
+
+
 def make_epoch_fn(
     problem: glm.GLMProblem | tuple,
     strategy,
@@ -230,6 +243,8 @@ def make_epoch_fn(
 
     Returns ``(init_state, epoch_fn, loss_fn, merges_per_epoch)``.  For
     SyncSGD the state is ``w [d]``; for AsyncLocalSGD it is ``W [R, d]``.
+    The data are arguments of the compiled programs, never constants
+    (:func:`_bind`).
 
     With ``step_param=True`` the epoch function takes ``(state, step)``
     with the step size as a traced scalar instead of baking the problem's
@@ -241,16 +256,30 @@ def make_epoch_fn(
     if sparse_data:
         task, m, y, step0 = problem
         n, d = m.shape
+        data = (m.values, m.indices, y)
     else:
         task, X, y, step0 = problem.task, problem.X, problem.y, problem.step
         n, d = X.shape
-        m = None
+        data = (X, y)
 
-    def _finalize(epoch_of_step):
+    def _finalize(epoch_of_step, data):
         """Bind the step statically, or expose it as a traced argument."""
         if step_param:
-            return jax.jit(epoch_of_step)
-        return jax.jit(lambda state: epoch_of_step(state, step0))
+            return _bind(epoch_of_step, data)
+        return _bind(lambda data, state: epoch_of_step(data, state, step0),
+                     data)
+
+    def _ell(v, i):
+        return sparse.ELLMatrix(v, i, d)
+
+    if sparse_data:
+        def loss(data, w):
+            v, i, y = data
+            return sparse.loss(task, _ell(v, i), y, w)
+    else:
+        def loss(data, w):
+            X, y = data
+            return glm.LOSSES[task](w, X, y)
 
     if isinstance(strategy, SyncSGD):
         batch = strategy.batch or n
@@ -269,29 +298,27 @@ def make_epoch_fn(
                 )
                 from repro.kernels.glm_sparse import ell_glm_grad as _kgrad_sp
 
-                @jax.jit
-                def epoch(w):
+                def epoch(data, w):
+                    v, i, y = data
                     if batch >= n:
-                        g = _kgrad_sp(task, w, m.values, m.indices, y,
-                                      backend=backend)
+                        g = _kgrad_sp(task, w, v, i, y, backend=backend)
                         return w - step0 * g
-                    return _kepoch_sp(task, w, m.values, m.indices, y,
-                                      step=step0, micro_batch=batch,
-                                      backend=backend)
+                    return _kepoch_sp(task, w, v, i, y, step=step0,
+                                      micro_batch=batch, backend=backend)
+
+                epoch = _bind(epoch, data)
 
             else:
 
-                def epoch_s(w, step):
+                def epoch_s(data, w, step):
+                    v, i, y = data
                     if batch >= n:
-                        g = sparse.grad(task, m, y, w)
+                        g = sparse.grad(task, _ell(v, i), y, w)
                         return w - (step / n) * g * n  # alpha on sum grad
-                    return sparse.minibatch_epoch(task, w, m, y, step, batch)
+                    return sparse.minibatch_epoch(task, w, _ell(v, i), y,
+                                                  step, batch)
 
-                epoch = _finalize(epoch_s)
-
-            @jax.jit
-            def loss_fn(w):
-                return sparse.loss(task, m, y, w)
+                epoch = _finalize(epoch_s, data)
 
         else:
             if backend is not None:
@@ -301,30 +328,29 @@ def make_epoch_fn(
                 from repro.kernels.glm_grad import glm_grad as _kgrad
                 from repro.kernels.glm_sgd import glm_sgd_epoch as _kepoch
 
-                @jax.jit
-                def epoch(w):
+                def epoch(data, w):
+                    X, y = data
                     if batch >= n:
                         g = _kgrad(task, w, X, y, backend=backend)
                         return w - step0 * g
                     return _kepoch(task, w, X, y, step=step0,
                                    micro_batch=batch, backend=backend)
 
+                epoch = _bind(epoch, data)
+
             else:
 
-                def epoch_s(w, step):
+                def epoch_s(data, w, step):
+                    X, y = data
                     if batch >= n:
                         g = glm.grad_fused(task, w, X, y)
                         return w - step * g
                     return glm.minibatch_epoch(task, w, X, y, step, batch)
 
-                epoch = _finalize(epoch_s)
-
-            @jax.jit
-            def loss_fn(w):
-                return glm.LOSSES[task](w, X, y)
+                epoch = _finalize(epoch_s, data)
 
         init = jnp.zeros((d,), dtype=jnp.float32)
-        return init, epoch, loss_fn, 0
+        return init, epoch, _bind(loss, data), 0
 
     assert isinstance(strategy, AsyncLocalSGD)
     R = strategy.replicas
@@ -337,23 +363,21 @@ def make_epoch_fn(
     per = parts.shape[1]
     merges = max(1, int(round(1.0 / strategy.merge_every))) if strategy.merge_every <= 1 else 1
     # merge_every > 1 handled by the driver (merge every int(merge_every) epochs)
+    # per-replica partitions: [R, per, ...] for every array of the data
+    parted = tuple(jnp.take(a, parts, axis=0) for a in data)
 
     if sparse_data:
-        vals_p = jnp.take(m.values, parts, axis=0)   # [R, per, K]
-        idx_p = jnp.take(m.indices, parts, axis=0)
-        y_p = jnp.take(y, parts, axis=0)
-
         if backend is not None:
             if strategy.local_batch == per:
                 # full-partition update: glm_sparse sum gradient
                 from repro.kernels.glm_sparse import ell_glm_grad as _kgrad_sp
 
-                def _replica_epoch(W, step):
+                def _replica_epoch(parted, W, step):
                     def one(w, v, i, yr):
                         g = _kgrad_sp(task, w, v, i, yr, backend=backend)
                         return w - (step / per) * g
 
-                    return jax.vmap(one)(W, vals_p, idx_p, y_p)
+                    return jax.vmap(one)(W, *parted)
 
             elif per % strategy.local_batch == 0:
                 # mini-batch local updates: fused sparse-SGD epoch kernel
@@ -361,13 +385,13 @@ def make_epoch_fn(
                     ell_sgd_epoch as _kepoch_sp,
                 )
 
-                def _replica_epoch(W, step):
+                def _replica_epoch(parted, W, step):
                     def one(w, v, i, yr):
                         return _kepoch_sp(task, w, v, i, yr, step=step,
                                           micro_batch=strategy.local_batch,
                                           backend=backend)
 
-                    return jax.vmap(one)(W, vals_p, idx_p, y_p)
+                    return jax.vmap(one)(W, *parted)
 
             else:
                 raise ValueError(
@@ -377,26 +401,12 @@ def make_epoch_fn(
 
         else:
 
-            def _replica_epoch(W, step):
+            def _replica_epoch(parted, W, step):
+                vals_p, idx_p, y_p = parted
                 return _sparse_replica_epoch(
                     task, W, vals_p, idx_p, d, y_p, step, strategy.local_batch)
 
-        def epoch_s(W, step):
-            for _ in range(merges):
-                W = _replica_epoch(W, step)
-                W = merge_replicas(W, strategy.merge)
-            return W
-
-        epoch = _finalize(epoch_s)
-
-        @jax.jit
-        def loss_fn(W):
-            return sparse.loss(task, m, y, W[0])
-
     else:
-        Xp = jnp.take(X, parts, axis=0)              # [R, per, d]
-        y_p = jnp.take(y, parts, axis=0)
-
         if backend is not None:
             if per % strategy.local_batch != 0:
                 raise ValueError(
@@ -405,34 +415,30 @@ def make_epoch_fn(
                     f"{strategy.local_batch}")
             from repro.kernels.glm_sgd import glm_sgd_epoch as _kepoch
 
-            def _replica_epoch(W, step):
+            def _replica_epoch(parted, W, step):
                 def one(w, Xr, yr):
                     return _kepoch(task, w, Xr, yr, step=step,
                                    micro_batch=strategy.local_batch,
                                    backend=backend)
 
-                return jax.vmap(one)(W, Xp, y_p)
+                return jax.vmap(one)(W, *parted)
 
         else:
 
-            def _replica_epoch(W, step):
+            def _replica_epoch(parted, W, step):
+                Xp, y_p = parted
                 return _dense_replica_epoch(
                     task, W, Xp, y_p, step, strategy.local_batch)
 
-        def epoch_s(W, step):
-            for _ in range(merges):
-                W = _replica_epoch(W, step)
-                W = merge_replicas(W, strategy.merge)
-            return W
-
-        epoch = _finalize(epoch_s)
-
-        @jax.jit
-        def loss_fn(W):
-            return glm.LOSSES[task](W[0], X, y)
+    def epoch_s(parted, W, step):
+        for _ in range(merges):
+            W = _replica_epoch(parted, W, step)
+            W = merge_replicas(W, strategy.merge)
+        return W
 
     init = jnp.zeros((R, d), dtype=jnp.float32)
-    return init, epoch, loss_fn, merges
+    return (init, _finalize(epoch_s, parted),
+            _bind(lambda data, W: loss(data, W[0]), data), merges)
 
 
 def run(

@@ -1,5 +1,5 @@
-"""Shared kernel infrastructure: backend dispatch registry, Pallas
-version-compat shims, and tiling helpers.
+"""Shared kernel infrastructure: backend dispatch registry, tiling
+helpers, and the ELL one-hot gather/scatter the sparse kernels share.
 
 The paper implements every SGD primitive "in two flavors" — CPU routines
 and highly-parallel GPU kernels — and picks per dataset/hardware.  The
@@ -41,29 +41,6 @@ from repro.obs import metrics, trace
 
 LANE = 128      # TPU minor-dim tile (VREG lanes / MXU edge)
 SUBLANE = 8     # fp32 second-minor tile
-
-# ---------------------------------------------------------------------------
-# Pallas version-compat shim
-# ---------------------------------------------------------------------------
-
-
-def tpu_compiler_params(**kwargs):
-    """Construct TPU compiler params across the Pallas API rename.
-
-    jax <= 0.4.x exposes ``pltpu.TPUCompilerParams``; newer releases
-    renamed it to ``pltpu.CompilerParams``.  Every kernel goes through
-    this shim so the drift is absorbed in exactly one place.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        raise RuntimeError(
-            "this Pallas exposes neither CompilerParams nor TPUCompilerParams;"
-            " jax >= 0.4.30 is required (see pyproject.toml)")
-    return cls(**kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -232,6 +209,12 @@ def resolve_backend(
         f"registered: {backends_for(kernel)}")
 
 
+def implementation(kernel: str, backend: str) -> Callable:
+    """The registered ``backend`` flavor of ``kernel``, with no resolution
+    — for compiling one flavor for a device this host does not have."""
+    return _REGISTRY[kernel][backend].fn
+
+
 def dispatch(
     kernel: str,
     *args,
@@ -245,7 +228,7 @@ def dispatch(
     # host-side dispatch span: under jit this times trace/lowering overhead
     # (the launch itself is async); outside jit it times the dispatch call
     with trace.span("kernel.dispatch", kernel=kernel, backend=b):
-        return _REGISTRY[kernel][b].fn(*args, **kwargs)
+        return implementation(kernel, b)(*args, **kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -295,3 +278,69 @@ def pick_block(size: int, preferred: int, multiple: int = 1) -> int:
         f"{multiple}, and {size} is not itself a multiple of {multiple}; "
         f"pad the operand to {padded(size, multiple)} or pick a backend "
         f"without the alignment constraint")
+
+
+def micro_batch_ok(n: int, micro_batch: int) -> bool:
+    """Can a fused Pallas epoch tile ``n`` rows into ``micro_batch`` blocks?
+
+    The epoch is a fixed grid, so ``micro_batch`` must divide ``n``, and
+    a ``[micro_batch, ...]`` block must obey Mosaic's tiling rule: its
+    row count is a multiple of 8 or the whole array.
+    """
+    return (micro_batch >= 1 and n % micro_batch == 0
+            and (micro_batch % SUBLANE == 0 or micro_batch == n))
+
+
+def check_micro_batch(kernel: str, n: int, micro_batch: int) -> None:
+    """Raise the ``ValueError`` a forced Pallas epoch gives a bad tile."""
+    if not micro_batch_ok(n, micro_batch):
+        raise ValueError(
+            f"{kernel} Pallas flavors need micro_batch to divide n and to "
+            f"be a multiple of {SUBLANE} or equal to n (Mosaic's row "
+            f"tiling rule), got n={n}, micro_batch={micro_batch}; drop the "
+            f"explicit backend to fall through to 'reference', or pick "
+            f"such a divisor of n")
+
+
+# ---------------------------------------------------------------------------
+# ELL one-hot gather / scatter (sparse kernel bodies)
+# ---------------------------------------------------------------------------
+
+#: VMEM the ``[rows, d, K]`` fp32 one-hot may take.  Gather and scatter
+#: each make a temporary of the same size, and the scoped VMEM default
+#: is 16 MiB on v5e.
+ONEHOT_VMEM_BYTES = 4 * 2 ** 20
+
+
+def onehot_fits(rows: int, d: int, k: int) -> bool:
+    """Does the fp32 one-hot of a ``[rows, K]`` tile over ``d`` features
+    fit :data:`ONEHOT_VMEM_BYTES`, with ``d`` padded to sublanes and
+    ``K`` to lanes?"""
+    size = rows * padded(max(d, 1), SUBLANE) * padded(max(k, 1), LANE) * 4
+    return size <= ONEHOT_VMEM_BYTES
+
+
+def ell_onehot(idx: jax.Array, d: int) -> jax.Array:
+    """One-hot ``[R, d, K]`` fp32 of an ELL index tile ``[R, K]``.
+
+    The feature axis sits on sublanes and the ELL width stays on lanes,
+    so no reshape moves data across lanes (Mosaic refuses the
+    ``[R, K] -> [R*K, 1]`` shape cast).  Out-of-range ids match nothing.
+    """
+    rows, k = idx.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, d, k), 1)
+    return (idx[:, None, :] == iota).astype(jnp.float32)
+
+
+def onehot_gather(onehot: jax.Array, w: jax.Array) -> jax.Array:
+    """``w[idx]`` as ``[R, K]`` from the one-hot and a ``[d, 1]`` model.
+
+    Exactly one term of each sum is non-zero, so the gather is exact.
+    """
+    return jnp.sum(onehot * w[None], axis=1)
+
+
+def onehot_scatter(onehot: jax.Array, c: jax.Array) -> jax.Array:
+    """Scatter-add ``[R, K]`` contributions into a ``[d, 1]`` column."""
+    return jnp.sum(jnp.sum(onehot * c[:, None, :], axis=0), axis=1,
+                   keepdims=True)

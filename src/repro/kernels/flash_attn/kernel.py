@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
 
 NEG_INF = -1e30
 
@@ -122,7 +121,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),    # running max
             pltpu.VMEM((block_q, 1), jnp.float32),    # running normalizer
         ],
-        compiler_params=common.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-
 
 def _pull(task: str, margins: jax.Array, y: jax.Array) -> jax.Array:
     if task == "lr":
@@ -97,7 +95,7 @@ def glm_grad_pallas(
         ],
         out_specs=pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),  # g accumulator
         out_shape=jax.ShapeDtypeStruct((d_pad, 1), jnp.float32),
-        compiler_params=common.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # revisited output block
         ),
         interpret=interpret,

@@ -4,12 +4,12 @@
 One launch scores a whole padded micro-batch: the model is pinned in
 VMEM across the row-tile grid (BlockSpec index map is constant, so the
 pipeline never re-streams it), and — like every sparse family here —
-the per-row gather ``w[idx]`` lowers to a dense one-hot MXU matmul over
-the full padded feature axis:
+the per-row gather ``w[idx]`` is a dense one-hot product over the full
+padded feature axis (:func:`repro.kernels.common.ell_onehot`):
 
     grid step i:  load ELL tile vals_i/idx_i [TB, K]  (HBM->VMEM stream)
-                  onehot  = (idx_i == iota_d)                  [TB*K, d]
-                  margins = rowsum(vals_i * onehot @ w)        (MXU)
+                  onehot  = (idx_i == iota_d)                  [TB, d, K]
+                  margins = rowsum(vals_i * sum_d(onehot * w)) (VPU)
                   out_i   = link(margins)                      (VPU)
 
 The link (LR sigmoid / SVM identity) is fused into the launch, so a
@@ -18,9 +18,9 @@ paper's coalesced sparse model access (§5.2.1).  Padded ELL entries
 (value 0 at index 0) contribute 0 to the margin, and padded *rows*
 (admission-queue filler) are entirely zero, so their margin is exactly
 0 and the engine just drops their scores; no masking is needed.  The
-one-hot spans the full padded feature axis, so ops.py budgets
-``TB * K * d_pad`` bytes against VMEM and routes over-budget problems
-to the reference oracle.
+one-hot spans the full padded feature axis, so ops.py budgets its VMEM
+(:func:`repro.kernels.common.onehot_fits`) and routes over-budget
+problems to the reference oracle.
 """
 from __future__ import annotations
 
@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -42,16 +43,9 @@ def _link(task, margins):
 def _kernel(task, vals_ref, idx_ref, w_ref, out_ref):
     vals = vals_ref[...]              # [TB, K]
     idx = idx_ref[...]                # [TB, K] int32 (global feature ids)
-    tb, kk = vals.shape
-    d_pad = w_ref.shape[0]
-
-    # one-hot [TB*K, d_pad] — the MXU-side gather operand
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (tb * kk, d_pad), 1)
-    onehot = (idx.reshape(tb * kk, 1) == iota_d).astype(jnp.float32)
-
-    w = w_ref[...]                    # [d_pad, 1] (VMEM-pinned)
-    wg = jnp.dot(onehot, w, preferred_element_type=jnp.float32)  # [TB*K, 1]
-    margins = jnp.sum(vals * wg.reshape(tb, kk), axis=1, keepdims=True)
+    onehot = common.ell_onehot(idx, w_ref.shape[0])       # [TB, d_pad, K]
+    wg = common.onehot_gather(onehot, w_ref[...])         # [TB, K] = w[idx]
+    margins = jnp.sum(vals * wg, axis=1, keepdims=True)
     out_ref[...] = _link(task, margins)
 
 
@@ -79,7 +73,7 @@ def glm_score_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-        compiler_params=common.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),  # rows are independent
         ),
         interpret=interpret,

@@ -2,15 +2,15 @@
 
 The ``reference`` flavor is the ``lax.scan`` gather/link oracle; the
 Pallas flavors score one padded micro-batch per launch with the model
-pinned in VMEM and the gather lowered to one-hot MXU matmuls
-(kernel.py).  Rows are independent, so — unlike the fused SGD epoch —
-there is no divisibility cap: N is zero-padded up to ``block_rows`` and
-the filler scores are sliced off.  One capability gate routes problems
-the one-hot cannot shape to the oracle: the ``block_rows * K * d_pad``
-VMEM budget (the one-hot spans the full padded feature axis because the
-model never leaves VMEM).  When the caller does not pin ``block_rows``,
-the per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
-before the built-in default.
+pinned in VMEM and the gather lowered to a one-hot product (kernel.py).
+Rows are independent, so — unlike the fused SGD epoch — there is no
+divisibility cap: N is zero-padded up to ``block_rows`` and the filler
+scores are sliced off.  One capability gate routes problems the one-hot
+cannot shape to the oracle: its VMEM budget
+(:func:`repro.kernels.common.onehot_fits`; the one-hot spans the full
+padded feature axis because the model never leaves VMEM).  When the
+caller does not pin ``block_rows``, the per-device autotuner cache
+(:mod:`repro.kernels.tune`) is consulted before the built-in default.
 """
 from __future__ import annotations
 
@@ -26,21 +26,13 @@ from repro.kernels.glm_score import ref as R
 #: built-in row tile when neither the caller nor the tuner pins one
 DEFAULT_BLOCK_ROWS = 8
 
-#: the one-hot operand [TB*K, d_pad] fp32 must stay a small VMEM tenant
-#: next to the pinned model and the streamed ELL tiles
-_MAX_ONEHOT_BYTES = 4 * 2 ** 20
-
-
-def onehot_budget_ok(d: int, k: int, block_rows: int) -> bool:
-    d_pad = common.padded(max(d, 1), common.LANE)
-    return block_rows * k * d_pad * 4 <= _MAX_ONEHOT_BYTES
-
 
 def _caps_check(info: dict) -> bool:
     d, k = info.get("d"), info.get("k")
     if d is not None and k is not None:
-        return onehot_budget_ok(d, k, info.get("block_rows",
-                                               DEFAULT_BLOCK_ROWS))
+        return common.onehot_fits(
+            info.get("block_rows", DEFAULT_BLOCK_ROWS),
+            common.padded(d, common.LANE), k)
     return True
 
 

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-
 
 def _pull(task, margins, y):
     if task == "lr":
@@ -81,7 +79,7 @@ def glm_sgd_pallas(
         out_specs=pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d_pad, 1), jnp.float32)],
-        compiler_params=common.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: state carried
         ),
         interpret=interpret,

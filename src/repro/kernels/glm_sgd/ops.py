@@ -4,13 +4,16 @@ The ``reference`` flavor is the sequential lax.scan oracle (ref.py); the
 Pallas flavors run one kernel launch per epoch with the model pinned in
 VMEM.  All flavors update in fp32.
 
-The Pallas flavors need ``n`` divisible by ``micro_batch`` (the epoch is
-a fixed-shape grid of micro-batch tiles): the dispatch caps see both in
-the call info, so auto-selection falls through to ``reference`` (which
-handles the ragged tail) instead of dying inside the kernel — forcing a
-Pallas flavor onto a non-divisible ``n`` raises ``ValueError``.  When
-the caller does not pin ``micro_batch``, the per-device autotuner cache
-(:mod:`repro.kernels.tune`) is consulted before the built-in default.
+The Pallas flavors need ``micro_batch`` to tile ``n`` (the epoch is a
+fixed-shape grid of ``[micro_batch, d]`` tiles, so it must divide ``n``
+and be a multiple of 8 or ``n`` itself —
+:func:`repro.kernels.common.micro_batch_ok`): the dispatch caps see both
+in the call info, so auto-selection falls through to ``reference``
+(which handles any micro-batch and the ragged tail) instead of dying
+inside the kernel — forcing a Pallas flavor onto such a call raises
+``ValueError``.  When the caller does not pin ``micro_batch``, the
+per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
+before the built-in default.
 """
 from __future__ import annotations
 
@@ -27,18 +30,9 @@ from repro.kernels.glm_sgd import ref as R
 DEFAULT_MICRO_BATCH = 8
 
 
-def _check_divisible(n: int, micro_batch: int) -> None:
-    if micro_batch < 1 or n % micro_batch:
-        raise ValueError(
-            f"glm_sgd Pallas flavors need n % micro_batch == 0, got "
-            f"n={n}, micro_batch={micro_batch}; drop the explicit backend "
-            f"to fall through to 'reference' (ragged-tail oracle) or pick "
-            f"a divisor of n")
-
-
 _PALLAS_CAPS = common.Caps(check=lambda info: (
     info.get("n") is None or info.get("micro_batch") is None
-    or (info["micro_batch"] >= 1 and info["n"] % info["micro_batch"] == 0)))
+    or common.micro_batch_ok(info["n"], info["micro_batch"])))
 
 
 @functools.partial(
@@ -47,11 +41,11 @@ _PALLAS_CAPS = common.Caps(check=lambda info: (
 def _pallas(task, w, X, y, *, step, micro_batch, interpret):
     """One fused SGD epoch over (X, y); model stays in VMEM throughout.
 
-    N must be divisible by ``micro_batch`` (checked, ValueError); d is
+    ``micro_batch`` must obey the tiling rule (checked, ValueError); d is
     padded to the 128-lane tile internally.
     """
     n, d = X.shape
-    _check_divisible(n, micro_batch)
+    common.check_micro_batch("glm_sgd", n, micro_batch)
     d_pad = common.padded(d, common.LANE)
     Xp = common.pad_to(X.astype(jnp.float32), 1, d_pad)
     yp = y.astype(jnp.float32).reshape(n, 1)

@@ -4,17 +4,18 @@ The dense ``glm_sgd`` kernel fuses gradient + update into one launch with
 the model pinned in VMEM scratch (Section 5's Hogwild-kernel analogue).
 This is its sparse sibling: the per-step example tile is a padded-ELL
 ``(values, indices)`` pair, and — like ``glm_sparse`` — the gather and
-scatter against the VMEM-resident model become dense one-hot MXU matmuls:
+scatter against the VMEM-resident model become dense one-hot products
+(:func:`repro.kernels.common.ell_onehot`):
 
     grid step k:  load ELL tile vals_k/idx_k [MB, K] (HBM->VMEM stream)
-                  onehot  = (idx_k == iota_d)                 [MB*K, d]
-                  margins = y_k * rowsum(vals_k * onehot@w)   (MXU)
-                  w_vmem -= (alpha/MB) * onehot^T (vals*pull) (MXU + VPU)
+                  onehot  = (idx_k == iota_d)                  [MB, d, K]
+                  margins = y_k * rowsum(vals_k * sum_d(onehot * w))
+                  w_vmem -= (alpha/MB) * sum_{MB,K}(onehot * vals*pull)
 
 One launch = one epoch = N/MB model updates with zero HBM traffic for
 the model.  The one-hot spans the *full* padded feature axis (no
 d-blocking): the model must stay live across steps, so ops.py budgets
-``MB * K * d_pad`` against VMEM and routes over-budget problems to the
+the one-hot against VMEM and routes over-budget problems to the
 reference oracle.  Padded ELL entries (value 0) contribute 0 to both the
 margin and the scatter, so no masking is needed.
 """
@@ -44,22 +45,13 @@ def _kernel(task, scale, vals_ref, idx_ref, y_ref, w0_ref, out_ref, w_s):
     vals = vals_ref[...]              # [MB, K]
     idx = idx_ref[...]                # [MB, K] int32 (global feature ids)
     y = y_ref[...]                    # [MB, 1]
-    mb, kk = vals.shape
-    d_pad = w_s.shape[0]
-
-    # one-hot [MB*K, d_pad] — gather AND scatter operand for the MXU
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (mb * kk, d_pad), 1)
-    onehot = (idx.reshape(mb * kk, 1) == iota_d).astype(jnp.float32)
+    onehot = common.ell_onehot(idx, w_s.shape[0])         # [MB, d_pad, K]
 
     w = w_s[...]                      # [d_pad, 1]
-    wg = jnp.dot(onehot, w, preferred_element_type=jnp.float32)  # [MB*K, 1]
-    margins = y * jnp.sum(vals * wg.reshape(mb, kk), axis=1, keepdims=True)
+    wg = common.onehot_gather(onehot, w)                  # [MB, K] = w[idx]
+    margins = y * jnp.sum(vals * wg, axis=1, keepdims=True)
     pull = _pull(task, margins, y)    # [MB, 1]
-    contrib = (vals * pull).reshape(mb * kk, 1)
-    g = jax.lax.dot_general(          # onehot^T @ contrib -> [d_pad, 1]
-        onehot, contrib, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    g = common.onehot_scatter(onehot, vals * pull)        # [d_pad, 1]
     w_s[...] = w - scale * g
 
     @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
@@ -95,7 +87,7 @@ def ell_sgd_pallas(
         out_specs=pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d_pad, 1), jnp.float32)],
-        compiler_params=common.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: state carried
         ),
         interpret=interpret,

@@ -2,15 +2,17 @@
 
 The ``reference`` flavor is the gather/segment-sum lax.scan oracle; the
 Pallas flavors run one launch per epoch with the model pinned in VMEM and
-gather/scatter lowered to one-hot MXU matmuls (kernel.py).
+gather/scatter lowered to one-hot products (kernel.py).
 
 Two capability gates route problems the kernel cannot shape to the
-oracle: ``n % micro_batch == 0`` (the epoch is a fixed grid of tiles) and
-a one-hot VMEM budget ``MB * K * d_pad`` (the one-hot spans the full
-padded feature axis because the model never leaves VMEM).  Forcing a
-Pallas flavor past the divisibility gate raises ``ValueError``.  When the
-caller does not pin ``micro_batch``, the per-device autotuner cache
-(:mod:`repro.kernels.tune`) is consulted before the built-in default.
+oracle: the micro-batch tiling rule (:func:`repro.kernels.common.
+micro_batch_ok`: a divisor of n that is a multiple of 8 or n itself) and
+the one-hot VMEM budget (:func:`repro.kernels.common.onehot_fits`; the
+one-hot spans the full padded feature axis because the model never
+leaves VMEM).  Forcing a Pallas flavor past the tiling rule raises
+``ValueError``.  When the caller does not pin ``micro_batch``, the
+per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
+before the built-in default.
 """
 from __future__ import annotations
 
@@ -26,32 +28,14 @@ from repro.kernels.glm_sgd_sparse import ref as R
 #: built-in micro-batch when neither the caller nor the tuner pins one
 DEFAULT_MICRO_BATCH = 8
 
-#: the one-hot operand [MB*K, d_pad] fp32 must stay a small VMEM tenant
-#: next to the pinned model and the streamed ELL tiles
-_MAX_ONEHOT_BYTES = 4 * 2 ** 20
-
-
-def onehot_budget_ok(d: int, k: int, micro_batch: int) -> bool:
-    d_pad = common.padded(max(d, 1), common.LANE)
-    return micro_batch * k * d_pad * 4 <= _MAX_ONEHOT_BYTES
-
-
-def _check_divisible(n: int, micro_batch: int) -> None:
-    if micro_batch < 1 or n % micro_batch:
-        raise ValueError(
-            f"glm_sgd_sparse Pallas flavors need n % micro_batch == 0, got "
-            f"n={n}, micro_batch={micro_batch}; drop the explicit backend "
-            f"to fall through to 'reference' (ragged-tail oracle) or pick "
-            f"a divisor of n")
-
 
 def _caps_check(info: dict) -> bool:
     n, mb = info.get("n"), info.get("micro_batch")
-    if n is not None and mb is not None and (mb < 1 or n % mb):
+    if n is not None and mb is not None and not common.micro_batch_ok(n, mb):
         return False
     d, k = info.get("d"), info.get("k")
     if d is not None and k is not None and mb is not None:
-        return onehot_budget_ok(d, k, mb)
+        return common.onehot_fits(mb, common.padded(d, common.LANE), k)
     return True
 
 
@@ -64,12 +48,12 @@ _PALLAS_CAPS = common.Caps(sparse=True, check=_caps_check)
 def _pallas(task, w, values, indices, y, *, step, micro_batch, interpret):
     """One fused sparse SGD epoch; model stays in VMEM throughout.
 
-    N must be divisible by ``micro_batch`` (checked, ValueError); d is
+    ``micro_batch`` must obey the tiling rule (checked, ValueError); d is
     padded to the 128-lane tile internally.
     """
     n, _ = values.shape
     d = w.shape[0]
-    _check_divisible(n, micro_batch)
+    common.check_micro_batch("glm_sgd_sparse", n, micro_batch)
     d_pad = common.padded(d, common.LANE)
     vp = values.astype(jnp.float32)
     ip = indices.astype(jnp.int32)

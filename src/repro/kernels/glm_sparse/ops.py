@@ -1,10 +1,10 @@
 """Public wrapper for the ELL sparse GLM gradient — registry-dispatched.
 
-The Pallas one-hot-MXU flavors carry a capability budget (one-hot FLOPs
-grow with d; the margin scratch burns N*4 bytes of VMEM), so very wide /
-very tall problems auto-route to the ``reference`` XLA gather/segment-sum
-flavor — the sparse analogue of the paper's per-dataset optimal-
-configuration finding (Table 6).
+The Pallas one-hot flavors carry a capability budget (one-hot work grows
+with d; the ``[block_rows, d_block, K]`` one-hot must fit its VMEM share),
+so very wide problems, or rows too wide for the one-hot, auto-route to the
+``reference`` XLA gather/segment-sum flavor — the sparse analogue of the
+paper's per-dataset optimal-configuration finding (Table 6).
 """
 from __future__ import annotations
 
@@ -17,18 +17,21 @@ from repro.kernels import common, tune
 from repro.kernels.glm_sparse import kernel as K
 from repro.kernels.glm_sparse import ref as R
 
-# Budget heuristics for the Pallas path.
-_MAX_D_PALLAS = 32_768      # one-hot FLOPs grow with d
-_MAX_N_PALLAS = 131_072     # margin scratch = N * 4 bytes of VMEM
+#: one-hot work grows with d
+_MAX_D_PALLAS = 32_768
+DEFAULT_BLOCK_ROWS = 8
+DEFAULT_D_BLOCK = 512
 
 
-def pallas_path_ok(n: int, d: int) -> bool:
-    return d <= _MAX_D_PALLAS and n <= _MAX_N_PALLAS
+def pallas_path_ok(d: int, k: int) -> bool:
+    """Width budget, and the default tile's one-hot fits VMEM."""
+    return (d <= _MAX_D_PALLAS
+            and common.onehot_fits(DEFAULT_BLOCK_ROWS, DEFAULT_D_BLOCK, k))
 
 
 _PALLAS_CAPS = common.Caps(
     sparse=True,
-    check=lambda info: pallas_path_ok(info.get("n", 0), info.get("d", 0)),
+    check=lambda info: pallas_path_ok(info.get("d", 0), info.get("k", 1)),
 )
 
 
@@ -52,14 +55,16 @@ def _pallas(task, w, values, indices, y, *, block_rows, d_block, interpret):
 
 
 @common.register_kernel("glm_sparse", common.PALLAS_TPU, caps=_PALLAS_CAPS)
-def _glm_sparse_tpu(task, w, values, indices, y, *, block_rows=8, d_block=512):
+def _glm_sparse_tpu(task, w, values, indices, y, *,
+                    block_rows=DEFAULT_BLOCK_ROWS, d_block=DEFAULT_D_BLOCK):
     return _pallas(task, w, values, indices, y, block_rows=block_rows,
                    d_block=d_block, interpret=False)
 
 
 @common.register_kernel("glm_sparse", common.PALLAS_INTERPRET, caps=_PALLAS_CAPS)
-def _glm_sparse_interpret(task, w, values, indices, y, *, block_rows=8,
-                          d_block=512):
+def _glm_sparse_interpret(task, w, values, indices, y, *,
+                          block_rows=DEFAULT_BLOCK_ROWS,
+                          d_block=DEFAULT_D_BLOCK):
     return _pallas(task, w, values, indices, y, block_rows=block_rows,
                    d_block=d_block, interpret=True)
 
@@ -68,8 +73,9 @@ def _glm_sparse_interpret(task, w, values, indices, y, *, block_rows=8,
     "glm_sparse", common.REFERENCE, caps=common.Caps(dtypes=None, sparse=True)
 )
 @functools.partial(jax.jit, static_argnames=("task", "block_rows", "d_block"))
-def _glm_sparse_reference(task, w, values, indices, y, *, block_rows=8,
-                          d_block=512):
+def _glm_sparse_reference(task, w, values, indices, y, *,
+                          block_rows=DEFAULT_BLOCK_ROWS,
+                          d_block=DEFAULT_D_BLOCK):
     del block_rows, d_block
     return R.ell_glm_grad_ref(
         task, w.astype(jnp.float32), values.astype(jnp.float32), indices,
@@ -94,21 +100,22 @@ def ell_glm_grad(
 
     Unpinned ``block_rows``/``d_block`` consult the autotuner cache
     (:mod:`repro.kernels.tune`); with no cached winner the historical
-    defaults (8, 512) apply.
+    defaults (``DEFAULT_BLOCK_ROWS``, ``DEFAULT_D_BLOCK``) apply.
     """
-    n, d = values.shape[0], w.shape[0]
+    n, kk = values.shape
+    d = w.shape[0]
     if force_path == "xla":
         backend = backend or common.REFERENCE
     elif force_path == "pallas" and backend is None:
         # legacy forcing bypassed the budget; interpret= picks the flavor
         use_interp = (not common.on_tpu()) if interpret is None else interpret
         backend = common.PALLAS_INTERPRET if use_interp else common.PALLAS_TPU
-    elif backend is None and interpret is not None and pallas_path_ok(n, d):
+    elif backend is None and interpret is not None and pallas_path_ok(d, kk):
         # legacy interpret= chose the Pallas mode but never overrode the
         # budget: over-budget problems still take the reference path
         backend = common.PALLAS_INTERPRET if interpret else common.PALLAS_TPU
     info = {"dtype": jnp.result_type(values).name, "sparse": True,
-            "n": n, "d": d}
+            "n": n, "d": d, "k": kk}
     b = common.resolve_backend("glm_sparse", backend=backend, info=info)
     if block_rows is None and d_block is None:
         run = None
@@ -120,7 +127,7 @@ def ell_glm_grad(
         d_block = cfg.get("d_block")
     return common.dispatch(
         "glm_sparse", task, w, values, indices, y,
-        block_rows=block_rows if block_rows is not None else 8,
-        d_block=d_block if d_block is not None else 512,
+        block_rows=DEFAULT_BLOCK_ROWS if block_rows is None else block_rows,
+        d_block=DEFAULT_D_BLOCK if d_block is None else d_block,
         backend=b, info=info,
     )

@@ -68,11 +68,12 @@ def tune_dir() -> Path:
 
 
 def device_kind() -> str:
-    """Normalized accelerator model string — part of every cache key."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover - no backend at all
-        kind = "unknown"
+    """Normalized accelerator model string — part of every cache key.
+
+    Raises whatever JAX raises when no backend can start: a cache entry
+    keyed to a made-up device would be served to the wrong hardware.
+    """
+    kind = jax.devices()[0].device_kind
     return str(kind).strip().lower().replace(" ", "-")
 
 
@@ -126,19 +127,26 @@ def _row_block_candidates(info: dict) -> tuple[dict, ...]:
 
 
 def _micro_batch_candidates(info: dict) -> tuple[dict, ...]:
-    """Micro-batch sizes that divide N (the fused-epoch divisibility cap)."""
+    """Micro-batch sizes a fused Pallas epoch can tile N with
+    (:func:`repro.kernels.common.micro_batch_ok`): multiples of 8 that
+    divide N, or, for a small N with none, the whole epoch as one tile."""
     n = int(info.get("n", 0))
-    mbs = [b for b in (1, 2, 4, 8, 16, 32, 64, 128) if n and n % b == 0]
-    return tuple({"micro_batch": b} for b in (mbs or [1]))
+    mbs = [b for b in (8, 16, 32, 64, 128) if common.micro_batch_ok(n, b)]
+    if not mbs and 0 < n <= 128:
+        mbs = [n]
+    return tuple({"micro_batch": b} for b in mbs)
 
 
 def _sparse_candidates(info: dict) -> tuple[dict, ...]:
+    """Row tile x d-block pairs whose one-hot fits VMEM."""
     n_pad = common.padded(max(int(info.get("n", 0)), 1), common.SUBLANE)
     rows = [b for b in (8, 16, 32) if b <= n_pad] or [8]
     d = max(int(info.get("d", 0)), 1)
+    k = int(info.get("k", 1))
     dbs = [db for db in (128, 256, 512) if db <= common.padded(d, 128)]
     return tuple({"block_rows": b, "d_block": db}
-                 for b in rows for db in (dbs or [128]))
+                 for b in rows for db in (dbs or [128])
+                 if common.onehot_fits(b, db, k))
 
 
 def _attn_candidates(info: dict) -> tuple[dict, ...]:

@@ -4,9 +4,11 @@ Per (arch x shape x mesh) cell, derive the three roofline terms from the
 compiled partitioned HLO (see hlo.py for why the text is parsed rather than
 trusting cost_analysis):
 
-    compute_s    = dot_FLOPs_per_device / MXU peak      (197e12 bf16)
-    memory_s     = HBM traffic proxy    / HBM bandwidth (819e9)
-    collective_s = wire bytes per device / ICI bandwidth (45e9 effective)
+    compute_s    = dot_FLOPs_per_device / MXU peak
+    memory_s     = HBM traffic proxy    / HBM bandwidth
+    collective_s = wire bytes per device / ICI bandwidth
+
+with the peaks of the target chip from :data:`PEAKS`.
 
 plus MODEL_FLOPS (6*N_active*D train / 2*N_active*D inference), the useful-
 compute ratio, the dominant term, and a one-line hillclimb suggestion.
@@ -33,9 +35,31 @@ import json
 import math
 from pathlib import Path
 
-PEAK_FLOPS = 197e12          # bf16 MXU per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 45e9                # effective bytes/s per link (of ~50 peak)
+#: Per-chip peaks keyed by normalized ``device_kind``
+#: (:func:`repro.kernels.tune.device_kind`).  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of interconnect (four links of 50 GB/s; 45 GB/s is the
+#: effective figure used here).
+PEAKS = {
+    "tpu-v5-lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 45e9},
+}
+
+#: the chip the dry-run and kernel rooflines are priced for
+TARGET_DEVICE = "tpu-v5-lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip; a chip not in :data:`PEAKS` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}") from None
+
+
+PEAK_FLOPS = peaks(TARGET_DEVICE)["flops"]      # bf16 MXU per chip
+HBM_BW = peaks(TARGET_DEVICE)["hbm_bw"]         # bytes/s per chip
+ICI_BW = peaks(TARGET_DEVICE)["ici_bw"]         # effective bytes/s per link
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "dryrun_results"
 

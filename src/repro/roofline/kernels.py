@@ -24,7 +24,7 @@ def kernel_cost(kernel: str, info: dict) -> dict:
     ``info`` is the same call-info dict the dispatch layer sees (n/d for
     the GLM families, k for ELL sparsity, batch/heads/seqs/head_dim for
     attention).  Sparse families are priced at their *useful* work (the
-    gather/scatter math), not the one-hot MXU FLOPs the TPU lowering
+    gather/scatter math), not the one-hot work the TPU lowering
     spends to avoid irregular access — the roofline is the task's bound,
     not the implementation's.
     """

@@ -13,7 +13,7 @@ probabilities, SVM margins) built from three pieces —
   wobble (the serving analogue of the study runner's vmap-stacked
   grids);
 * **the fused scoring kernel** — ``kernels/glm_score``: one launch per
-  batch, model pinned in VMEM, ELL gather as one-hot MXU matmuls, the
+  batch, model pinned in VMEM, ELL gather as a one-hot product, the
   task link (LR sigmoid / SVM identity) fused in.  Dispatch goes
   through the standard three-backend registry, so the engine runs
   anywhere the conformance suite does;

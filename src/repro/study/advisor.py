@@ -291,11 +291,25 @@ def candidate_space(
     replicas: Sequence[int] = (4, 16, 64),
     accesses: Sequence[str] = ("chunk", "round_robin"),
     rep_ks: Sequence[int] = (0, 10),
-    kernel_backends: Sequence[str | None] = (None, "pallas-interpret"),
+    kernel_backends: Sequence[str | None] | None = None,
 ) -> list:
-    """Table-6 design space, filtered to what host + dataset can run."""
+    """Table-6 design space, filtered to what host + dataset can run.
+
+    ``kernel_backends`` defaults to the XLA path plus the host's own
+    Pallas flavor: compiled on a TPU, the interpreter elsewhere.  The
+    interpreter is never a candidate on a TPU, where timing it would
+    rank the emulator instead of the chip.
+    """
+    from repro.kernels import common as kcommon
+
+    on_tpu = caps.platform == "tpu"
+    if kernel_backends is None:
+        kernel_backends = (None, kcommon.PALLAS_TPU if on_tpu
+                           else kcommon.PALLAS_INTERPRET)
     out: list = []
     for kb in kernel_backends:
+        if kb == kcommon.PALLAS_INTERPRET and on_tpu:
+            continue
         if kb is not None:
             # forcing a backend bypasses the dispatch Caps checks, so the
             # space must self-limit: kernel-backed sync epochs are dense

@@ -99,12 +99,30 @@ class TrialResult:
 
 
 class TrialCache:
-    """Content-addressed on-disk cache: ``<root>/<trial.key>.json``."""
+    """Content-addressed on-disk cache: ``<root>/<trial.key>.json``.
+
+    Each file is stamped with the ``device_kind`` that produced it, and a
+    file from another device (or with no stamp) is a miss: a trial timed
+    on the CPU says nothing about the chip, and a copied tree carries its
+    cache along.  Payloads come back without the stamp, so results and
+    the stores built from them do not depend on it.
+    """
+
+    #: stamp field; the leading underscore keeps it clear of payload keys
+    DEVICE_FIELD = "_device_kind"
 
     def __init__(self, root: str | Path | None):
         self.root = Path(root) if root is not None else None
         self.hits = 0
         self.misses = 0
+        self._device_kind: str | None = None
+
+    @property
+    def device_kind(self) -> str:
+        if self._device_kind is None:   # lazily: touching JAX is not free
+            from repro.kernels.tune import device_kind
+            self._device_kind = device_kind()
+        return self._device_kind
 
     def get(self, key: str) -> dict | None:
         payload = self.peek(key)
@@ -122,16 +140,20 @@ class TrialCache:
             return None
         try:
             with open(self.root / f"{key}.json") as f:
-                return json.load(f)
+                payload = json.load(f)
         except (FileNotFoundError, json.JSONDecodeError):
             return None
+        if payload.pop(self.DEVICE_FIELD, None) != self.device_kind:
+            return None
+        return payload
 
     def put(self, key: str, payload: dict) -> None:
         if self.root is None:
             return
         self.root.mkdir(parents=True, exist_ok=True)
         tmp = self.root / f".{key}.tmp.{os.getpid()}"
-        tmp.write_text(canonical_json(payload))
+        tmp.write_text(canonical_json(
+            {**payload, self.DEVICE_FIELD: self.device_kind}))
         tmp.replace(self.root / f"{key}.json")  # atomic on POSIX
 
 
@@ -146,6 +168,16 @@ def _problem(ds, task: str, step: float):
 def _stackable(t: TrialSpec) -> bool:
     """Kernel-backend epochs bake the step statically → no step stacking."""
     return getattr(t.strategy, "kernel_backend", None) is None
+
+
+def _call(fn, *args):
+    return fn(*args)
+
+
+# The engine's functions are pytrees that carry their data, passed
+# unbatched, so the data stays an argument of the stacked program.
+_stacked_epoch = jax.jit(jax.vmap(_call, in_axes=(None, 0, 0)))
+_stacked_loss = jax.jit(jax.vmap(_call, in_axes=(None, 0)))
 
 
 class Runner:
@@ -292,25 +324,27 @@ class Runner:
                 step_param=True)
             steps = jnp.asarray([t.step for t in group], dtype=jnp.float32)
             state = jnp.stack([init] * S)
-            epoch_v = jax.jit(jax.vmap(epoch_fn))
-            loss_v = jax.jit(jax.vmap(loss_fn))
 
-            losses = [np.asarray(loss_v(state), dtype=np.float64)]
+            losses = [np.asarray(_stacked_loss(loss_fn, state),
+                                 dtype=np.float64)]
             times: list[float] = []
             with trace.span("engine.compile", strategy=base.strategy.name,
                             stacked=S):
-                state = epoch_v(state, steps)      # warmup epoch (compiles)
+                # warmup epoch (compiles)
+                state = _stacked_epoch(epoch_fn, state, steps)
                 jax.block_until_ready(state)
-            losses.append(np.asarray(loss_v(state), dtype=np.float64))
+            losses.append(np.asarray(_stacked_loss(loss_fn, state),
+                                     dtype=np.float64))
             times.append(float("nan"))
             for e in range(base.epochs - 1):
                 with trace.span("engine.epoch", epoch=e + 1,
                                 strategy=base.strategy.name, stacked=S):
                     t0 = time.perf_counter()
-                    state = epoch_v(state, steps)
+                    state = _stacked_epoch(epoch_fn, state, steps)
                     jax.block_until_ready(state)
                     times.append(time.perf_counter() - t0)
-                losses.append(np.asarray(loss_v(state), dtype=np.float64))
+                losses.append(np.asarray(_stacked_loss(loss_fn, state),
+                                         dtype=np.float64))
             times[0] = (float(np.nanmedian(times[1:]))
                         if len(times) > 1 else 0.0)
 

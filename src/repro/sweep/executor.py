@@ -135,6 +135,13 @@ class LocalProcessExecutor:
                  worker_args: Sequence[str] = ()):
         if workers < 1:
             raise ValueError(f"workers must be >= 1: {workers}")
+        from repro.kernels.common import on_tpu
+        if on_tpu():
+            raise ValueError(
+                "LocalProcessExecutor runs trials in worker subprocesses, "
+                "but a TPU belongs to one process at a time and this one "
+                "holds it; run the sweep in-process (benchmarks.run "
+                "--workers 1)")
         self.workers = workers
         self.work_dir = Path(work_dir) if work_dir is not None else None
         self.max_retries = max_retries

@@ -111,4 +111,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

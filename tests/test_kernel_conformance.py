@@ -195,9 +195,16 @@ def test_glm_grad_col_layout_conformance(backend, glm_data):
 @pytest.mark.parametrize("backend", common.available_backends("glm_sgd"))
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
 @pytest.mark.parametrize("task", TASKS)
-@pytest.mark.parametrize("mb", [1, 4])
+@pytest.mark.parametrize("mb", [1, 4, 8, 32])
 def test_glm_sgd_conformance(backend, dtype, task, mb, glm_data):
+    """Every flavor matches the oracle; a forced Pallas flavor refuses a
+    micro-batch that breaks Mosaic's row tiling (not 8k, not n)."""
     X, y, w = glm_data(32, 40, dtype)
+    if backend != common.REFERENCE and not common.micro_batch_ok(32, mb):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            glm_sgd_epoch(task, w, X, y, step=0.02, micro_batch=mb,
+                          backend=backend)
+        return
     ref = glm_sgd_epoch_ref(task, *_f32(w, X, y), 0.02, mb)
     out = glm_sgd_epoch(task, w, X, y, step=0.02, micro_batch=mb,
                         backend=backend)
@@ -228,9 +235,14 @@ def test_glm_sgd_caps_route_ragged_n_to_reference(glm_data):
     common.available_backends("glm_sgd_sparse", info={"sparse": True}))
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
 @pytest.mark.parametrize("task", TASKS)
-@pytest.mark.parametrize("mb", [1, 4])
+@pytest.mark.parametrize("mb", [1, 4, 8, 32])
 def test_glm_sgd_sparse_conformance(backend, dtype, task, mb, ell_data):
     values, indices, y, w = ell_data(32, 200, 6, dtype)
+    if backend != common.REFERENCE and not common.micro_batch_ok(32, mb):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            ell_sgd_epoch(task, w, values, indices, y, step=0.05,
+                          micro_batch=mb, backend=backend)
+        return
     ref = ell_sgd_epoch_ref(task, *_f32(w, values), indices,
                             y.astype(jnp.float32), 0.05, mb)
     out = ell_sgd_epoch(task, w, values, indices, y, step=0.05,
@@ -254,10 +266,9 @@ def test_glm_sgd_sparse_caps_route_ragged_n_to_reference(ell_data):
 
 def test_glm_sgd_sparse_caps_route_over_budget_to_reference():
     """A one-hot too large for VMEM routes to the oracle automatically."""
-    from repro.kernels.glm_sgd_sparse.ops import onehot_budget_ok
-
-    assert onehot_budget_ok(d=4096, k=8, micro_batch=16)
-    assert not onehot_budget_ok(d=1_000_000, k=8, micro_batch=16)
+    # the one-hot is [MB, d_pad, K] with K padded to 128 lanes
+    assert common.onehot_fits(16, 384, 8)
+    assert not common.onehot_fits(16, 1_000_000, 8)
     info = {"dtype": "float32", "sparse": True, "n": 64, "d": 1_000_000,
             "k": 8, "micro_batch": 16}
     assert (common.resolve_backend("glm_sgd_sparse", info=info)
@@ -355,14 +366,12 @@ def test_glm_score_padding_rows_contribute_exactly_zero(backend):
 
 def test_glm_score_caps_route_over_budget_to_reference():
     """A one-hot too large for VMEM routes scoring to the oracle."""
-    from repro.kernels.glm_score.ops import onehot_budget_ok
-
-    assert onehot_budget_ok(d=4096, k=8, block_rows=8)
-    assert not onehot_budget_ok(d=1_000_000, k=8, block_rows=8)
+    assert common.onehot_fits(8, 512, 8)
+    assert not common.onehot_fits(8, 1_000_000, 8)
     info = {"dtype": "float32", "sparse": True, "n": 32, "d": 1_000_000,
             "k": 8}
     assert common.resolve_backend("glm_score", info=info) == common.REFERENCE
-    small = dict(info, d=4096)
+    small = dict(info, d=512)
     assert common.resolve_backend("glm_score", info=small) != common.REFERENCE
 
 
@@ -432,13 +441,19 @@ def test_glm_grad_backends_agree_pairwise(task, glm_data):
 
 
 @pytest.mark.parametrize("backend", common.available_backends("glm_sgd"))
-@pytest.mark.parametrize("local_batch", [1, 4])
+@pytest.mark.parametrize("local_batch", [1, 4, 8, 16])
 def test_async_engine_kernel_backend_dense(backend, local_batch, glm_data):
     from repro.core import glm, sgd
 
     X, y, _ = glm_data(64, 16)
     prob = glm.GLMProblem("lr", X, y, 5e-3)
     strat = sgd.AsyncLocalSGD(replicas=4, local_batch=local_batch)
+    if backend != common.REFERENCE and not common.micro_batch_ok(
+            64 // 4, local_batch):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            sgd.run(prob, dataclasses.replace(strat, kernel_backend=backend),
+                    3, record_time=False)
+        return
     base = sgd.run(prob, strat, 3, record_time=False)
     routed = sgd.run(
         prob, dataclasses.replace(strat, kernel_backend=backend), 3,
@@ -477,7 +492,7 @@ def test_async_engine_kernel_backend_sparse(backend):
     sp = synthetic.make_sparse("sp-async", 64, 128, 5.0, 8, seed=4)
     per = 64 // 4
     prob = ("lr", sp.ell, jnp.asarray(sp.y), 0.05)
-    for local_batch in (per, 4):
+    for local_batch in (per, 8):
         base = sgd.run(
             prob, sgd.AsyncLocalSGD(replicas=4, local_batch=local_batch), 3,
             sparse_data=True, record_time=False)
@@ -487,6 +502,11 @@ def test_async_engine_kernel_backend_sparse(backend):
             sparse_data=True, record_time=False)
         np.testing.assert_allclose(routed.losses, base.losses,
                                    rtol=1e-4, atol=1e-4)
+    if backend != common.REFERENCE:   # 4 divides 16 but breaks the tiling
+        with pytest.raises(ValueError, match="multiple of 8"):
+            sgd.run(prob, sgd.AsyncLocalSGD(replicas=4, local_batch=4,
+                                            kernel_backend=backend), 3,
+                    sparse_data=True, record_time=False)
     with pytest.raises(ValueError, match="divide the"):
         sgd.make_epoch_fn(
             prob, sgd.AsyncLocalSGD(replicas=4, local_batch=5,

@@ -60,10 +60,13 @@ def test_cache_round_trip_is_canonical_json(tmp_path):
 def test_micro_batch_candidates_divide_n():
     cands = tune.TUNABLES["glm_sgd"].candidates({"n": 96})
     mbs = [c["micro_batch"] for c in cands]
-    assert mbs and all(96 % m == 0 for m in mbs)
-    # prime n still yields the trivial candidate
+    assert mbs == [8, 16, 32]
+    assert all(common.micro_batch_ok(96, m) for m in mbs)
+    # a small prime n leaves the whole epoch as one tile
     assert tune.TUNABLES["glm_sgd"].candidates({"n": 97}) \
-        == ({"micro_batch": 1},)
+        == ({"micro_batch": 97},)
+    # a large n with no multiple of 8 dividing it has no Pallas tile
+    assert tune.TUNABLES["glm_sgd"].candidates({"n": 581_011}) == ()
 
 
 def test_attn_candidates_divide_both_sequences():
@@ -207,3 +210,15 @@ def test_autotune_env_tunes_and_reuses(tmp_path, monkeypatch, glm_data):
     stamp = recs[0].stat().st_mtime_ns
     glm_grad("lr", w, X, y)
     assert recs[0].stat().st_mtime_ns == stamp
+
+
+def test_device_kind_raises_without_a_backend(monkeypatch):
+    """No silent "unknown" key: a cache entry must name real hardware."""
+    import jax
+
+    def boom():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="no backend"):
+        tune.device_kind()

@@ -100,8 +100,9 @@ def test_ell_sgd_kernel(task, mb, n, d, k, rng):
 def test_glm_sparse_auto_path_picks_xla_when_huge(rng):
     """Very wide models route to the XLA gather path automatically."""
     from repro.kernels.glm_sparse.ops import pallas_path_ok
-    assert not pallas_path_ok(n=10_000, d=1_000_000)
-    assert pallas_path_ok(n=10_000, d=20_958)
+    assert not pallas_path_ok(d=1_000_000, k=16)
+    assert pallas_path_ok(d=20_958, k=16)
+    assert not pallas_path_ok(d=20_958, k=3_484)  # real-sim's widest row
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -26,12 +26,13 @@ def test_mini_mesh_sync_lowering_compiles():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro import configs
         from repro.launch import specs as S
+        from repro.launch.mesh import auto_mesh
         from repro.roofline import hlo
         from repro.train import trainer
         from repro.optim.sgd import sgd
 
         cfg = configs.reduced(configs.get('minitron-4b'), seq_shard=True)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = auto_mesh((4, 2), ('data', 'model'))
         p_shapes, p_specs = S.param_shapes_and_specs(cfg)
         b_shapes, b_specs = S.batch_specs(cfg, 'train', 16, 8)
         opt = sgd(1e-2)
@@ -60,10 +61,11 @@ def test_mini_mesh_decode_lowering_compiles():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro import configs
         from repro.launch import specs as S
+        from repro.launch.mesh import auto_mesh
         from repro.train import trainer
 
         cfg = configs.reduced(configs.get('zamba2-1.2b'))
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
         p_shapes, p_specs = S.param_shapes_and_specs(cfg)
         c_shapes, c_specs = S.cache_shapes_and_specs(cfg, 8, 32)
         b_shapes, b_specs = S.batch_specs(cfg, 'decode', 32, 8)

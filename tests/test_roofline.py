@@ -113,3 +113,13 @@ def test_glm_task_configs():
     assert c.async_rep_k == 10 and c.async_access == "round_robin"
     strat = c.async_strategy()
     assert strat.rep_k == 10
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.roofline import analysis
+
+    v5e = analysis.peaks("tpu-v5-lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert analysis.PEAK_FLOPS == analysis.peaks(analysis.TARGET_DEVICE)["flops"]
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.peaks("cpu")

@@ -101,6 +101,26 @@ def test_trial_cache_roundtrip_and_hit(tmp_path):
     assert not r.run_trial(_trial(epochs=4)).cached
 
 
+def test_trial_cache_misses_on_another_device(tmp_path):
+    """A trial cached on one device_kind is a miss on another, and an
+    unstamped file is a miss too; a hit comes back without the stamp."""
+    import json as _json
+
+    from repro.study.runner import TrialCache
+
+    cache = TrialCache(tmp_path / "cache")
+    cache.put("k", {"losses": [1.0]})
+    assert cache.peek("k") == {"losses": [1.0]}
+    on_disk = _json.loads((tmp_path / "cache" / "k.json").read_text())
+    assert on_disk[TrialCache.DEVICE_FIELD] == cache.device_kind
+    other = TrialCache(tmp_path / "cache")
+    other._device_kind = "tpu-v5-lite" if cache.device_kind != "tpu-v5-lite" \
+        else "cpu"
+    assert other.get("k") is None and other.misses == 1
+    (tmp_path / "cache" / "old.json").write_text('{"losses": [1.0]}')
+    assert cache.peek("old") is None
+
+
 def test_interrupted_sweep_resumes_from_cache(tmp_path):
     """Only the missing trials of a partially-cached sweep are executed."""
     trials = [_trial(step=s, epochs=3) for s in (1e-3, 1e-2, 1e-1)]
@@ -509,6 +529,27 @@ def test_candidate_space_respects_host_and_dataset():
     # rep-k never exceeds the partition size
     assert all(s.rep_k < prof.n // s.replicas for s in space
                if isinstance(s, sgd.AsyncLocalSGD))
+
+
+def test_candidate_space_uses_the_hosts_compiled_flavor():
+    """On a TPU host the kernel candidate is the compiled flavor, and the
+    interpreter is never one, even when asked for."""
+    prof = spec.DatasetSpec("covtype", max_n=128).profile()
+    tpu_caps = advisor.HostCaps(
+        parallel_width=8, max_replicas=16, platform="tpu",
+        backends={"glm_grad": ("pallas-tpu", "pallas-interpret",
+                               "reference")})
+    names = [s.name for s in advisor.candidate_space(prof, tpu_caps)]
+    assert "sync[pallas-tpu]" in names
+    assert not any("pallas-interpret" in n for n in names)
+    asked = advisor.candidate_space(
+        prof, tpu_caps, kernel_backends=(None, "pallas-interpret"))
+    assert not any("pallas-interpret" in s.name for s in asked)
+    cpu_caps = advisor.HostCaps(
+        parallel_width=8, max_replicas=16,
+        backends={"glm_grad": ("pallas-interpret", "reference")})
+    names = [s.name for s in advisor.candidate_space(prof, cpu_caps)]
+    assert "sync[pallas-interpret]" in names
 
 
 # ---------------------------------------------------------------------------

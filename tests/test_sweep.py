@@ -341,3 +341,12 @@ def test_dispatched_results_match_in_process_results(tmp_path):
         (tmp_path / "cache" / f"{trials[0].key}.json").read_text())
     restored = TrialResult.from_dict(payload)
     np.testing.assert_array_equal(restored.losses, dispatched[0].losses)
+
+
+def test_executor_refuses_worker_processes_on_a_tpu_host(monkeypatch):
+    """One process holds the chip: subprocess workers would hang on it."""
+    from repro.kernels import common as kcommon
+
+    monkeypatch.setattr(kcommon, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="one process"):
+        LocalProcessExecutor(workers=2)
