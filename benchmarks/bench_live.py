@@ -335,6 +335,7 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_live.json",
         print(f"windows={s['windows']} breaches={s['total_breaches']} "
               f"{s['breaches'] or ''}")
         metrics.flush(0)
+        trace.flush()
     return rows
 
 

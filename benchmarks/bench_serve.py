@@ -53,7 +53,7 @@ from repro.kernels import common as kcommon
 from repro.kernels import tune
 from repro.kernels.glm_score import glm_score
 from repro.kernels.glm_score.ref import glm_score_ref
-from repro.obs import metrics
+from repro.obs import metrics, trace
 from repro.obs.monitor import DEFAULT_SERVE_SLOS, HealthMonitor
 from repro.roofline import kernels as roofline
 from repro.serve.glm import GLMScoreEngine, ScoreRequest
@@ -293,6 +293,7 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_serve.json",
         print(f"windows={s['windows']} breaches={s['total_breaches']} "
               f"{s['breaches'] or ''}")
         metrics.flush(0)
+        trace.flush()
     return rows
 
 

@@ -225,10 +225,7 @@ def dispatch(
 ):
     """Resolve a backend and invoke the registered implementation."""
     b = resolve_backend(kernel, backend=backend, interpret=interpret, info=info)
-    # host-side dispatch span: under jit this times trace/lowering overhead
-    # (the launch itself is async); outside jit it times the dispatch call
-    with trace.span("kernel.dispatch", kernel=kernel, backend=b):
-        return implementation(kernel, b)(*args, **kwargs)
+    return implementation(kernel, b)(*args, **kwargs)
 
 
 # ---------------------------------------------------------------------------

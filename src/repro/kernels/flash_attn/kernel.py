@@ -125,4 +125,5 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attn",
     )(q, k, v)

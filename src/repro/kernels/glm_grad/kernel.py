@@ -99,4 +99,5 @@ def glm_grad_pallas(
             dimension_semantics=("arbitrary",),  # revisited output block
         ),
         interpret=interpret,
+        name="glm_grad",
     )(X, y, w)

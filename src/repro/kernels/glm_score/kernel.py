@@ -77,4 +77,5 @@ def glm_score_pallas(
             dimension_semantics=("parallel",),  # rows are independent
         ),
         interpret=interpret,
+        name="glm_score",
     )(values, indices, w)

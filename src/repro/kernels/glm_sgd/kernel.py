@@ -83,4 +83,5 @@ def glm_sgd_pallas(
             dimension_semantics=("arbitrary",),  # sequential: state carried
         ),
         interpret=interpret,
+        name="glm_sgd",
     )(X, y, w0)

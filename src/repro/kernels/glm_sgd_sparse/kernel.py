@@ -91,4 +91,5 @@ def ell_sgd_pallas(
             dimension_semantics=("arbitrary",),  # sequential: state carried
         ),
         interpret=interpret,
+        name="glm_sgd_sparse",
     )(values, indices, y, w0)

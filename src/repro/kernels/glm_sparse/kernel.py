@@ -112,6 +112,7 @@ def ell_glm_grad_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="glm_sparse_margins",
     )(values, indices, w)
 
     tile = pl.BlockSpec((block_rows, kk), lambda j, i: (i, 0))
@@ -126,4 +127,5 @@ def ell_glm_grad_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="glm_sparse_grad",
     )(values, indices, y, margins)

@@ -25,9 +25,11 @@ engine does not have, all previously dead code, are wired in:
   (``kernel_backend=None`` keeps the pure-XLA path), vmapped over the
   replica axis, jitted once: stream batches hold one shape by contract.
 
-Every step/merge emits ``live.step`` / ``live.merge`` spans and
-``live.*`` counters, so a traced run renders the learner next to the
-serving engine's ``serve.*`` spans on one timeline (docs/LIVE.md).
+Every step emits a ``live.step`` span over fetch, pass, merge and
+publish (children ``live.fetch``, ``live.put``, ``live.pass``,
+``live.merge``, ``live.publish``) and ``live.*`` counters, so a traced
+run renders the learner next to the serving engine's ``serve.*`` spans
+on one timeline (docs/LIVE.md).
 """
 from __future__ import annotations
 
@@ -129,6 +131,7 @@ class LiveLearner:
         self._merge_hooks: list[Callable[["LiveLearner"], None]] = []
         self._iter = iter(stream)
         self._epoch = self._build_epoch()
+        trace.count_compiles()   # a compile mid-stream is a stall
 
     # -- construction --------------------------------------------------------
 
@@ -235,31 +238,38 @@ class LiveLearner:
     def step(self) -> StreamBatch:
         """One stream step: fetch the next chunk, run one local pass on
         every *alive* replica, merge when the gate says so.  Returns the
-        consumed batch."""
-        batch = next(self._iter)
-        alive = self.gate.alive_mask()
-        with trace.span("live.step", step=self.steps, seq=batch.seq,
-                        alive=int(alive.sum())):
-            parts = self._parts
-            yp = jnp.asarray(batch.y[parts])
-            alive_j = jnp.asarray(alive)
-            if getattr(self.stream, "dense", False):
-                Xp = jnp.asarray(batch.X[parts])
-                self.W = self._epoch(self.W, Xp, yp, alive_j)
-            else:
-                vals_p = jnp.asarray(batch.values[parts])
-                idx_p = jnp.asarray(batch.indices[parts])
-                self.W = self._epoch(self.W, vals_p, idx_p, yp, alive_j)
-        # alive replicas made progress this step; dead ones stay silent
-        now_alive = np.nonzero(alive)[0]
-        for r in now_alive:
-            self.heartbeat.beat(int(r))
-        self.steps += 1
-        metrics.counter("live.steps").inc()
-        if self.gate.should_merge(self.steps):
-            self.merge()
-        if self.monitor is not None:
-            self.monitor.on_learner_step(self)
+        consumed batch.
+
+        Traced as ``live.step`` around the whole step, with children
+        ``live.fetch``, ``live.put`` (the per-replica index and the
+        copies to the device), ``live.pass`` (the epoch call until it
+        returns) and, on a merge, ``live.merge`` and ``live.publish``."""
+        with trace.span("live.step") as st:
+            alive = self.gate.alive_mask()
+            with trace.span("live.fetch"):
+                batch = next(self._iter)
+            if trace.enabled():
+                st.set(step=self.steps, seq=batch.seq, alive=int(alive.sum()))
+            with trace.span("live.put"):
+                parts = self._parts
+                if getattr(self.stream, "dense", False):
+                    xs = (jnp.asarray(batch.X[parts]),)
+                else:
+                    xs = (jnp.asarray(batch.values[parts]),
+                          jnp.asarray(batch.indices[parts]))
+                yp = jnp.asarray(batch.y[parts])
+                alive_j = jnp.asarray(alive)
+            with trace.span("live.pass"):
+                self.W = self._epoch(self.W, *xs, yp, alive_j)
+            # alive replicas made progress this step; dead ones stay silent
+            for r in np.nonzero(alive)[0]:
+                self.heartbeat.beat(int(r))
+            self.steps += 1
+            metrics.counter("live.steps").inc()
+            if self.gate.should_merge(self.steps):
+                self.merge()
+            if self.monitor is not None:
+                self.monitor.on_learner_step(self)
         return batch
 
     def merge(self) -> Array | None:

@@ -13,9 +13,10 @@ guaranteed ceiling (``every_merges * merge_every`` steps) as long as the
 publisher is attached and merges are not being skipped (at least one
 replica alive).
 
-Publishes emit ``live.publish`` spans and a ``live.publishes`` counter,
-completing the single-timeline story: ``live.step`` -> ``live.merge`` ->
-``live.publish`` -> ``serve.batch`` in one Perfetto trace.
+Publishes emit ``live.publish`` spans (inside the step's ``live.step``,
+after its ``live.merge``) and a ``live.publishes`` counter, completing the single-timeline story:
+``live.step`` -> ``live.merge`` -> ``live.publish`` -> the next
+``serve.flush`` that stamps the new version, in one Perfetto trace.
 """
 from __future__ import annotations
 

@@ -168,10 +168,12 @@ def snapshot() -> dict:
 
 def enabled() -> bool:
     """Whether the sidecar is persisted: ``REPRO_METRICS=1`` alone, or
-    implied by tracing.  The registry itself is always live; with both
-    off the only cost anywhere is this env lookup on flush paths (the
-    increment fast path never checks)."""
-    return os.environ.get(ENV_METRICS) == "1" or trace.enabled()
+    implied by tracing that exports (``REPRO_TRACE=1``; in-memory
+    ``trace.enable()`` writes no file).  The registry itself is always
+    live; with both off the only cost anywhere is this env lookup on
+    flush paths (the increment fast path never checks)."""
+    return (os.environ.get(ENV_METRICS) == "1"
+            or trace.current_dir() is not None)
 
 
 def sidecar_path() -> Path | None:

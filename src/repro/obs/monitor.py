@@ -425,6 +425,7 @@ class HealthMonitor:
                           value=value, op=slo.op, threshold=slo.threshold,
                           window=sample["window"])
         metrics.flush()                  # best-effort sidecar durability
+        trace.flush()
 
     def roll(self) -> dict | None:
         """Force-close the current window; returns its sample (None when

@@ -27,9 +27,11 @@ probabilities, SVM margins) built from three pieces —
 
 Thread model: any number of producer threads may ``try_admit``/
 ``submit``; any number of consumer threads may ``flush`` (dequeue is
-under the lock, scoring is outside it).  Every path is traced
-(``serve.admit`` / ``serve.batch`` / ``serve.score`` spans) and counted
-when telemetry is on (docs/OBSERVABILITY.md).
+under the lock, scoring is outside it).  Every flush is counted
+(``serve.*`` counters, ``serve.queue_wait_us`` among them) and, with
+tracing on, spanned: ``serve.flush`` with children ``serve.pack``,
+``serve.put``, ``serve.dispatch``, ``serve.wait`` and ``serve.respond``
+(docs/SERVING.md, docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -142,6 +144,7 @@ class GLMScoreEngine:
         self.block_rows = block_rows
         self.fault_stall_s = fault_stall_s
         self.monitor = None
+        trace.count_compiles()   # a compile while serving is a stall
         self._clock = clock
         self._lock = threading.Lock()
         #: FIFO of (request, padded values row, padded indices row, t_admit)
@@ -207,11 +210,10 @@ class GLMScoreEngine:
         False so producers can retry/shed.
         """
         row = self._pad_row(req)
-        with trace.span("serve.admit", rid=req.rid):
-            with self._lock:
-                full = len(self._queue) >= self.queue_depth
-                if not full:
-                    self._queue.append((req, *row, self._clock()))
+        with self._lock:
+            full = len(self._queue) >= self.queue_depth
+            if not full:
+                self._queue.append((req, *row, self._clock()))
         if full:
             metrics.counter("serve.rejected").inc()
             if self.monitor is not None:
@@ -233,10 +235,11 @@ class GLMScoreEngine:
 
     # -- scoring -------------------------------------------------------------
 
-    def _dequeue(self, limit: int) -> list:
+    def _dequeue(self, limit: int) -> tuple[list, float]:
+        """Up to ``limit`` queued entries, FIFO, and the clock at dequeue."""
         with self._lock:
             n = min(limit, len(self._queue))
-            return [self._queue.popleft() for _ in range(n)]
+            return [self._queue.popleft() for _ in range(n)], self._clock()
 
     def flush(self) -> list[ScoreResponse]:
         """Score up to ``max_batch`` queued requests (FIFO) now.
@@ -244,40 +247,53 @@ class GLMScoreEngine:
         The batch is padded to exactly ``max_batch`` all-zero rows so the
         jitted launch sees one stable shape; filler scores are dropped.
         Returns one response per dequeued request, in admission order,
-        all stamped with the single snapshot that scored them.
+        all stamped with the single snapshot that scored them.  Counter
+        ``serve.queue_wait_us`` grows by the batch's summed wait from
+        admission to dequeue, on the engine's clock.
         """
-        entries = self._dequeue(self.max_batch)
-        if not entries:
+        if not self._queue:
             return []
-        snap = self._model       # ONE atomic snapshot read per batch
-        n = len(entries)
-        vals = np.zeros((self.max_batch, self.ell_width), np.float32)
-        idx = np.zeros((self.max_batch, self.ell_width), np.int32)
-        for i, (_, v, ix, _) in enumerate(entries):
-            vals[i] = v
-            idx[i] = ix
-        with trace.span("serve.batch", rows=n, padded=self.max_batch,
-                        version=snap.version):
+        with trace.span("serve.flush") as fl:
+            with trace.span("serve.pack"):
+                entries, t_deq = self._dequeue(self.max_batch)
+                if not entries:
+                    return []                   # another consumer took them
+                snap = self._model   # ONE atomic snapshot read per batch
+                n = len(entries)
+                vals = np.zeros((self.max_batch, self.ell_width), np.float32)
+                idx = np.zeros((self.max_batch, self.ell_width), np.int32)
+                for i, (_, v, ix, _) in enumerate(entries):
+                    vals[i] = v
+                    idx[i] = ix
+            metrics.counter("serve.queue_wait_us").inc(
+                round(sum(t_deq - e[3] for e in entries) * 1e6))
+            if trace.enabled():
+                fl.set(rows=n, first_rid=entries[0][0].rid,
+                       last_rid=entries[-1][0].rid, version=snap.version)
             if self.fault_stall_s:
                 time.sleep(self.fault_stall_s)      # injected deadline stall
-            with trace.span("serve.score", backend=self.backend or "auto"):
-                scores = glm_score(
-                    snap.task, snap.w, jnp.asarray(vals), jnp.asarray(idx),
-                    block_rows=self.block_rows, backend=self.backend)
-                scores = np.asarray(
-                    jax.block_until_ready(scores), np.float32)
-        t1 = self._clock()
-        metrics.counter("serve.scored").inc(n)
-        metrics.counter("serve.batches").inc()
-        responses = [
-            ScoreResponse(req.rid, float(scores[i]), snap.version,
-                          max(0.0, t1 - t_admit))
-            for i, (req, _, _, t_admit) in enumerate(entries)
-        ]
-        if self.monitor is not None:
-            self.monitor.on_flush(
-                n=n, padded=self.max_batch, queue_depth=len(self._queue),
-                latencies=[r.latency_s for r in responses])
+            with trace.span("serve.put"):
+                vals, idx = jnp.asarray(vals), jnp.asarray(idx)
+            with trace.span("serve.dispatch"):
+                scores = glm_score(snap.task, snap.w, vals, idx,
+                                   block_rows=self.block_rows,
+                                   backend=self.backend)
+            with trace.span("serve.wait"):
+                scores = np.asarray(jax.block_until_ready(scores), np.float32)
+            with trace.span("serve.respond"):
+                t1 = self._clock()
+                metrics.counter("serve.scored").inc(n)
+                metrics.counter("serve.batches").inc()
+                responses = [
+                    ScoreResponse(req.rid, float(scores[i]), snap.version,
+                                  max(0.0, t1 - t_admit))
+                    for i, (req, _, _, t_admit) in enumerate(entries)
+                ]
+                if self.monitor is not None:
+                    self.monitor.on_flush(
+                        n=n, padded=self.max_batch,
+                        queue_depth=len(self._queue),
+                        latencies=[r.latency_s for r in responses])
         return responses
 
     def maybe_flush(self) -> list[ScoreResponse]:

@@ -23,7 +23,7 @@ first trip, so the retried shard runs to completion), or on every
 attempt without it (exercises retry exhaustion).  ``--fault-mode kill``
 makes the fault a real ``SIGKILL`` (no atexit, no cleanup) instead of
 ``sys.exit``: the durability story — per-group ``metrics.flush()`` and
-the runner's atomic cache writes — is what keeps the partial sidecar
+``trace.flush()`` and the runner's atomic cache writes — is what keeps the partial sidecar
 and cache readable, and the tests assert exactly that.
 """
 from __future__ import annotations
@@ -105,6 +105,7 @@ def main(argv=None) -> int:
             # durability point: everything this group counted is on disk
             # before a fault (even SIGKILL) can take the process down
             metrics.flush(0)
+            trace.flush()
             _maybe_fault(done, args.fault_after, args.fault_flag,
                          args.fault_mode)
     return 0

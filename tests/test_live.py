@@ -255,6 +255,40 @@ def test_publisher_stamps_steps_and_bounds_staleness():
                                np.asarray(lrn.anchor), rtol=1e-6)
 
 
+def test_live_step_span_tree():
+    """``live.step`` covers the whole step: fetch, put, pass, and on a
+    merge step ``live.merge`` and the publish that follows it."""
+    from repro.obs import trace
+
+    s = _stream()
+    eng = GLMScoreEngine("lr", np.zeros(D, np.float32),
+                         ell_width=s.ell_width, max_batch=8)
+    lrn = LiveLearner(_cfg(merge_every=2), s)
+    SnapshotPublisher(eng).attach(lrn)
+    lrn.step()                              # compile outside the trace
+    trace.enable()
+    try:
+        lrn.step()                          # step 2: merges and publishes
+        lrn.step()
+    finally:
+        trace.disable()
+    recs = [r for r in trace.records()     # no instants, no compiles
+            if r.kind == "span" and r.name != trace.COMPILE_SPAN]
+    steps = [r for r in recs if r.name == "live.step"]
+    assert [(r.depth, r.attrs["step"], r.attrs["alive"]) for r in steps] == [
+        (0, 1, 4), (0, 2, 4)]
+
+    def children(parent):
+        return [r.name for r in sorted(recs, key=lambda r: r.start_ns)
+                if r.parent == parent.id]
+
+    assert children(steps[0]) == ["live.fetch", "live.put", "live.pass",
+                                  "live.merge", "live.publish"]
+    assert children(steps[1]) == ["live.fetch", "live.put", "live.pass"]
+    assert len(recs) == 2 + 5 + 3
+    assert all(r.cpu_end_ns >= r.cpu_start_ns for r in steps)
+
+
 def test_publisher_validates_period():
     eng = GLMScoreEngine("lr", np.zeros(4, np.float32), ell_width=2)
     with pytest.raises(ValueError, match="every_merges"):

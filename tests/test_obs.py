@@ -11,10 +11,15 @@ The acceptance contract of the subsystem (ISSUE 7):
   one timeline with the driver.
 """
 import json
+import os
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import sgd
@@ -79,7 +84,7 @@ def test_spans_do_not_change_jit_lowering(traced):
         return jnp.tanh(x) * 2.0
 
     def instrumented(x):
-        with trace.span("kernel.dispatch", kernel="t"):
+        with trace.span("serve.dispatch", kernel="t"):
             return jnp.tanh(x) * 2.0
 
     # the module name embeds fn.__name__; align it so the only possible
@@ -89,8 +94,11 @@ def test_spans_do_not_change_jit_lowering(traced):
     x = jnp.ones((8, 8))
     lowered_plain = jax.jit(plain).lower(x).as_text()
     assert jax.jit(instrumented).lower(x).as_text() == lowered_plain
+    trace.flush()
     ft_before = export.read_trace(trace.current_path())
-    assert [s["name"] for s in ft_before.spans] == ["kernel.dispatch"]
+    # making x compiled a broadcast: tracing on records it as jax.compile
+    assert [s["name"] for s in ft_before.spans
+            if s["name"] != trace.COMPILE_SPAN] == ["serve.dispatch"]
 
 
 # ---------------------------------------------------------------------------
@@ -112,6 +120,7 @@ def test_span_roundtrip_nesting_and_chrome_export(traced):
 
     assert tuned() == 42
 
+    trace.flush()
     ft = export.read_trace(trace.current_path())
     assert ft.tag == trace.DEFAULT_TAG
     names = [s["name"] for s in ft.spans]
@@ -139,6 +148,7 @@ def test_span_records_error_and_schema_gate(traced):
     with pytest.raises(RuntimeError):
         with trace.span("sweep.execute"):
             raise RuntimeError("boom")
+    trace.flush()
     ft = export.read_trace(trace.current_path())
     assert ft.spans[0]["args"]["error"] == "RuntimeError"
 
@@ -154,6 +164,7 @@ def test_span_records_error_and_schema_gate(traced):
 def test_report_cli_check_and_perfetto(traced, capsys):
     with trace.span("runner.trial", key="k"):
         pass
+    trace.flush()
     out_json = traced / "merged.json"
     assert report.main([str(traced), "--check"]) == 0
     assert report.main([str(traced), "--perfetto", str(out_json)]) == 0
@@ -174,6 +185,7 @@ def test_report_json_is_machine_readable(traced, capsys):
             pass
     trace.instant("kernel.caps_fallback", chosen="reference")
     metrics.counter("serve.scored").inc(7)
+    trace.flush()
     metrics.write_sidecar()
     capsys.readouterr()
     assert report.main([str(traced), "--json"]) == 0
@@ -221,6 +233,160 @@ def test_chrome_events_stitch_skewed_per_file_clock_anchors(tmp_path):
     assert evs["a.work"]["ts"] == pytest.approx(0.0)
     assert evs["b.work"]["ts"] == pytest.approx(600_000.0)
     assert export.validate_chrome(export.to_chrome(traces)) == []
+
+
+# ---------------------------------------------------------------------------
+# in-memory recording, thread CPU time, profiler mirror, export, compiles
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def in_memory(tmp_path, monkeypatch):
+    """Tracing off by env, with the trace dir pointed at an empty dir."""
+    monkeypatch.delenv(trace.ENV_TRACE, raising=False)
+    monkeypatch.setenv(trace.ENV_TRACE_DIR, str(tmp_path))
+    trace.refresh()
+    yield tmp_path
+    trace.disable()
+    trace.refresh()
+
+
+def test_enable_disable_records_in_memory_and_writes_no_file(in_memory):
+    assert not trace.enabled()
+    trace.enable()
+    assert trace.enabled() and trace.current_path() is None
+    with trace.span("serve.flush", rows=3) as fl:
+        with trace.span("serve.pack"):
+            pass
+        fl.set(version=7)
+        trace.instant("serve.swap", version=8)
+    trace.disable()
+    assert not trace.enabled()
+    assert trace.span("serve.flush") is trace.NOOP
+
+    recs = trace.records()                  # still readable after disable
+    assert [(r.name, r.kind, r.depth) for r in recs] == [
+        ("serve.pack", "span", 1), ("serve.swap", "instant", 1),
+        ("serve.flush", "span", 0)]
+    pack, swap, flush = recs
+    assert pack.parent == swap.parent == flush.id and flush.parent == 0
+    assert flush.attrs == {"rows": 3, "version": 7}
+    assert flush.start_ns <= pack.start_ns <= pack.end_ns <= flush.end_ns
+    assert len({pack.id, swap.id, flush.id}) == 3
+    assert metrics.write_sidecar() is None  # in-memory tracing persists nothing
+    assert list(in_memory.iterdir()) == []
+
+    trace.enable()                          # a fresh buffer each time
+    assert trace.records() == []
+
+
+def test_records_carry_thread_cpu_time(in_memory):
+    """Outermost spans carry the thread's CPU time; nested ones do not
+    (the clock is a system call)."""
+    trace.enable()
+    with trace.span("live.pass"):
+        time.sleep(0.05)                    # off the CPU
+    with trace.span("live.step"):
+        with trace.span("live.put"):
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.03:   # on it
+                pass
+    trace.disable()
+    slept, nested, busy = trace.records()
+    assert nested.cpu_start_ns is None and nested.cpu_end_ns is None
+    wall = slept.end_ns - slept.start_ns
+    assert wall >= 50_000_000
+    assert 0 <= slept.cpu_end_ns - slept.cpu_start_ns < wall / 2
+    cpu = busy.cpu_end_ns - busy.cpu_start_ns
+    assert 30_000_000 <= cpu <= busy.end_ns - busy.start_ns + 1_000_000
+
+
+def test_trace_annotation_mirror_keeps_lowering_and_reaches_the_profiler(
+        in_memory):
+    """Enabled spans enter ``jax.profiler.TraceAnnotation``: a jitted body
+    still lowers identically, and a profiler capture holds the spans on
+    its host plane."""
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    def plain(x):
+        return jnp.tanh(x) * 2.0
+
+    def instrumented(x):
+        with trace.span("serve.dispatch"):
+            return jnp.tanh(x) * 2.0
+
+    instrumented.__name__ = "plain"
+    x = jnp.ones((8, 8))
+    lowered_plain = jax.jit(plain).lower(x).as_text()
+    trace.enable()
+    assert trace._TRACER.annotation is TraceAnnotation
+    assert jax.jit(instrumented).lower(x).as_text() == lowered_plain
+
+    jax.profiler.start_trace(str(in_memory / "xprof"))
+    try:
+        with trace.span("serve.flush"):
+            with trace.span("serve.wait"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    trace.disable()
+    [path] = (in_memory / "xprof").rglob("*.xplane.pb")
+    host = [e.name for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU" for line in plane.lines
+            for e in line.events]
+    assert host.count("serve.flush") == 1 and host.count("serve.wait") == 1
+
+
+def test_export_at_flush_and_at_exit_in_the_file_format(traced):
+    with trace.span("runner.trial", key="k"):
+        with trace.span("engine.epoch", epoch=1):
+            pass
+    assert not trace.current_path().exists()    # buffered until a flush
+    assert trace.flush() == trace.current_path()
+    ft = export.read_trace(trace.current_path())
+    assert [(s["name"], s["depth"]) for s in ft.spans] == [
+        ("engine.epoch", 1), ("runner.trial", 0)]
+    assert set(ft.spans[1]) == {"kind", "name", "ts", "dur", "pid", "tid",
+                                "depth", "args"}
+    assert trace.records() == []                # exported records leave
+    assert trace.flush() == trace.current_path()
+    assert len(export.read_trace(trace.current_path()).spans) == 2
+
+    # a process that never flushes writes its spans at exit
+    code = ("from repro.obs import trace\n"
+            "with trace.span('sweep.shard', worker=0):\n"
+            "    trace.instant('sweep.mark')\n")
+    env = {**os.environ, "PYTHONPATH": "src", trace.ENV_TRACE_TAG: "child"}
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   cwd=Path(__file__).resolve().parents[1], timeout=120)
+    [child] = traced.glob("trace-child-*.jsonl")
+    ft = export.read_trace(child)
+    assert ft.tag == "child"
+    assert [s["name"] for s in ft.spans] == ["sweep.shard"]
+    assert [i["name"] for i in ft.instants] == ["sweep.mark"]
+    assert ft.spans[0]["args"] == {"worker": 0}
+    assert report.main([str(traced), "--check"]) == 0
+
+
+def test_jax_compiles_counts_new_shapes_not_repeats(in_memory):
+    trace.count_compiles()
+    count = metrics.counter(trace.COMPILE_COUNTER)
+    triple = jax.jit(lambda x: x * 3.0 + 1.0)
+    c0 = count.value
+    triple(np.ones(3, np.float32))
+    assert count.value == c0 + 1                # a new shape compiles
+    triple(np.ones(3, np.float32))
+    assert count.value == c0 + 1                # a repeat does not
+    trace.enable()
+    with trace.span("serve.dispatch"):
+        triple(np.ones(5, np.float32))
+    trace.disable()
+    assert count.value == c0 + 2
+    compile_, dispatch = trace.records()
+    assert compile_.name == trace.COMPILE_SPAN
+    assert "lambda" in compile_.attrs["fun_name"]
+    assert compile_.parent == dispatch.id and compile_.depth == 1
+    assert dispatch.start_ns <= compile_.end_ns <= dispatch.end_ns
 
 
 # ---------------------------------------------------------------------------
@@ -342,6 +508,7 @@ def test_traced_two_worker_sweep_produces_stitchable_timeline(traced,
     Runner(cache_dir=tmp_path / "cache", store=st, executor=ex).run(trials)
     st.write()
 
+    trace.flush()
     traces = export.collect([traced])
     tags = {t.tag for t in traces}
     assert trace.DEFAULT_TAG in tags                   # the driver

@@ -280,6 +280,46 @@ def test_score_engine_flush_deadline_with_injected_clock():
     assert len(eng.maybe_flush()) == 4  # full batch flushes regardless
 
 
+def test_score_engine_flush_span_tree_and_queue_wait_exact():
+    """``serve.flush`` holds pack/put/dispatch/wait/respond, carries the
+    batch's rows, rids and version; ``serve.queue_wait_us`` grows by the
+    summed admission-to-dequeue wait on the engine's clock, to the us."""
+    from repro.obs import metrics, trace
+
+    now = [1.0]
+    eng, _ = _score_engine(max_batch=4, queue_depth=8, clock=lambda: now[0])
+    waits = metrics.counter("serve.queue_wait_us")
+    trace.enable()
+    try:
+        for rid, t in [(5, 1.0), (6, 1.1), (7, 1.5)]:
+            now[0] = t
+            assert eng.try_admit(_req(rid))
+        now[0] = 2.0
+        before = waits.value
+        out = eng.flush()
+        assert waits.value - before == 1_000_000 + 900_000 + 500_000
+        assert eng.flush() == []            # empty queue: no span either
+    finally:
+        trace.disable()
+    assert [r.rid for r in out] == [5, 6, 7]
+    recs = [r for r in trace.records()     # no instants, no compiles
+            if r.kind == "span" and r.name != trace.COMPILE_SPAN]
+    assert not any(r.name.startswith("serve.admit") for r in recs)
+    [flush] = [r for r in recs if r.name == "serve.flush"]
+    assert flush.depth == 0
+    assert flush.attrs == {"rows": 3, "first_rid": 5, "last_rid": 7,
+                           "version": 0}
+    children = sorted((r for r in recs if r.parent == flush.id),
+                      key=lambda r: r.start_ns)
+    assert [r.name for r in children] == [
+        "serve.pack", "serve.put", "serve.dispatch", "serve.wait",
+        "serve.respond"]
+    assert all(flush.start_ns <= r.start_ns <= r.end_ns <= flush.end_ns
+               for r in children)
+    assert all(a.end_ns <= b.start_ns for a, b in zip(children, children[1:]))
+    assert len(recs) == 1 + len(children)
+
+
 def test_score_engine_swap_model_atomic_versioning():
     eng, w = _score_engine("svm", d=24)
     assert eng.model.version == 0
