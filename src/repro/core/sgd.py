@@ -252,6 +252,11 @@ def make_epoch_fn(
     whole §6.1 step-size grid in one program.  Kernel-backend epochs bake
     the step statically (it is a kernel compile-time constant) and refuse
     ``step_param``.
+
+    With a ``kernel_backend``, the sparse loss takes its margins from the
+    registry's ``glm_sparse_margins`` on that backend where its one-hot
+    fits the VMEM budget, and from the XLA gather of :func:`sparse.loss`
+    otherwise.
     """
     if sparse_data:
         task, m, y, step0 = problem
@@ -272,7 +277,20 @@ def make_epoch_fn(
     def _ell(v, i):
         return sparse.ELLMatrix(v, i, d)
 
-    if sparse_data:
+    backend = strategy.kernel_backend
+    kernel_loss = False
+    if sparse_data and backend is not None:
+        from repro.kernels.glm_sparse.ops import ell_margins, margins_fit
+
+        kernel_loss = margins_fit(d, m.max_nnz)
+
+    if kernel_loss:
+        # the margins through the registry; the link and sum stay in XLA
+        def loss(data, w):
+            v, i, y = data
+            return sparse.margin_loss(
+                task, y * ell_margins(w, v, i, backend=backend))
+    elif sparse_data:
         def loss(data, w):
             v, i, y = data
             return sparse.loss(task, _ell(v, i), y, w)
@@ -283,7 +301,6 @@ def make_epoch_fn(
 
     if isinstance(strategy, SyncSGD):
         batch = strategy.batch or n
-        backend = strategy.kernel_backend
         if backend is not None and step_param:
             raise ValueError(
                 "step_param needs kernel_backend=None (kernel epochs bake "
@@ -354,7 +371,6 @@ def make_epoch_fn(
 
     assert isinstance(strategy, AsyncLocalSGD)
     R = strategy.replicas
-    backend = strategy.kernel_backend
     if backend is not None and step_param:
         raise ValueError(
             "step_param needs kernel_backend=None (kernel epochs bake "

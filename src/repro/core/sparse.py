@@ -199,9 +199,11 @@ def grad(task: str, m: ELLMatrix, y: Array, w: Array) -> Array:
 
 
 def loss(task: str, m: ELLMatrix, y: Array, w: Array) -> Array:
-    from repro.core import glm
+    return margin_loss(task, y * margins(m, w))
 
-    mar = y * margins(m, w)
+
+def margin_loss(task: str, mar: Array) -> Array:
+    """Sum loss of the signed margins ``y_i * (x_i . w)``."""
     if task == "lr":
         return jnp.sum(jnp.maximum(-mar, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(mar))))
     return jnp.sum(jnp.maximum(0.0, 1.0 - mar))

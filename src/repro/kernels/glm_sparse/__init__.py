@@ -1,1 +1,1 @@
-from repro.kernels.glm_sparse.ops import ell_glm_grad  # noqa: F401
+from repro.kernels.glm_sparse.ops import ell_glm_grad, ell_margins  # noqa: F401

@@ -12,7 +12,9 @@ block* become dense products with a one-hot tile
 The gradient takes two launches:
 
     margins:  grid (row tile, d-block) — m_i = x_i . w, accumulated across
-              d-blocks into the row tile's [TB, 1] output block;
+              d-blocks into the row tile's [TB, 1] output block (alone,
+              with one d-block, it is the margins-only op the sparse
+              engine's loss check calls);
     gradient: grid (d-block, row tile) — pull_i = f'(y_i m_i), and the
               scatter of vals * pull accumulates into the d-block's output
               block (row tiles are contiguous per d-block, so it flushes
@@ -82,25 +84,23 @@ def _grad_kernel(task, d_block, vals_ref, idx_ref, y_ref, m_ref, g_ref):
     g_ref[...] += common.onehot_scatter(onehot, sel * pull)
 
 
-def ell_glm_grad_pallas(
-    task: str,
+def margins_pallas(
     w: jax.Array,        # [d_pad, 1]
     values: jax.Array,   # [N_pad, K]
     indices: jax.Array,  # [N_pad, K] int32
-    y: jax.Array,        # [N_pad, 1]
     *,
     block_rows: int,
     d_block: int,
     interpret: bool,
 ) -> jax.Array:
+    """``[N_pad, 1]`` margins ``x_i . w``; rows are independent."""
     n_pad, kk = values.shape
     d_pad = w.shape[0]
     assert n_pad % block_rows == 0 and d_pad % d_block == 0
-    rows, blocks = n_pad // block_rows, d_pad // d_block
     tile = pl.BlockSpec((block_rows, kk), lambda i, j: (i, 0))
-    margins = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_margin_kernel, d_block),
-        grid=(rows, blocks),
+        grid=(n_pad // block_rows, d_pad // d_block),
         in_specs=[
             tile,                                                # values
             tile,                                                # indices
@@ -114,6 +114,24 @@ def ell_glm_grad_pallas(
         interpret=interpret,
         name="glm_sparse_margins",
     )(values, indices, w)
+
+
+def ell_glm_grad_pallas(
+    task: str,
+    w: jax.Array,        # [d_pad, 1]
+    values: jax.Array,   # [N_pad, K]
+    indices: jax.Array,  # [N_pad, K] int32
+    y: jax.Array,        # [N_pad, 1]
+    *,
+    block_rows: int,
+    d_block: int,
+    interpret: bool,
+) -> jax.Array:
+    n_pad, kk = values.shape
+    d_pad = w.shape[0]
+    rows, blocks = n_pad // block_rows, d_pad // d_block
+    margins = margins_pallas(w, values, indices, block_rows=block_rows,
+                             d_block=d_block, interpret=interpret)
 
     tile = pl.BlockSpec((block_rows, kk), lambda j, i: (i, 0))
     column = pl.BlockSpec((block_rows, 1), lambda j, i: (i, 0))

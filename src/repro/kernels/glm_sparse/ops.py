@@ -1,10 +1,15 @@
-"""Public wrapper for the ELL sparse GLM gradient — registry-dispatched.
+"""Public wrappers for the ELL sparse GLM gradient and margins —
+registry-dispatched.
 
 The Pallas one-hot flavors carry a capability budget (one-hot work grows
 with d; the ``[block_rows, d_block, K]`` one-hot must fit its VMEM share),
 so very wide problems, or rows too wide for the one-hot, auto-route to the
 ``reference`` XLA gather/segment-sum flavor — the sparse analogue of the
 paper's per-dataset optimal-configuration finding (Table 6).
+
+``ell_margins`` (family ``glm_sparse_margins``) is the gradient's margins
+launch on its own, with the whole model in one d-block padded to the
+128-lane tile: the margins ``x_i . w`` a loss over ELL rows needs.
 """
 from __future__ import annotations
 
@@ -21,6 +26,10 @@ from repro.kernels.glm_sparse import ref as R
 _MAX_D_PALLAS = 32_768
 DEFAULT_BLOCK_ROWS = 8
 DEFAULT_D_BLOCK = 512
+#: row tile of the margins-only launch: on a TPU v5e at w8a's shape
+#: (64,696 x 300, K = 114) 8 rows take 4.0 ms a call, 16 rows 4.9 ms;
+#: 32 rows (5.9 ms) overflow the one-hot budget
+MARGINS_BLOCK_ROWS = 8
 
 
 def pallas_path_ok(d: int, k: int) -> bool:
@@ -131,3 +140,77 @@ def ell_glm_grad(
         d_block=DEFAULT_D_BLOCK if d_block is None else d_block,
         backend=b, info=info,
     )
+
+
+def margins_fit(d: int, k: int) -> bool:
+    """Does the margins launch's one-hot, over the whole model padded to
+    lanes, fit VMEM?"""
+    return common.onehot_fits(MARGINS_BLOCK_ROWS,
+                              common.padded(d, common.LANE), k)
+
+
+_MARGINS_CAPS = common.Caps(
+    sparse=True,
+    check=lambda info: margins_fit(info.get("d", 0), info.get("k", 1)),
+)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _margins_pallas(w, values, indices, *, interpret):
+    """One margins launch over row tiles, the model pinned as one d-block.
+
+    N is zero-padded up to the row tile and the filler rows sliced off
+    (a zero row's margin is 0, which is not a zero loss).
+    """
+    n, _ = values.shape
+    d = w.shape[0]
+    n_pad = common.padded(n, MARGINS_BLOCK_ROWS)
+    d_pad = common.padded(d, common.LANE)
+    vp = common.pad_to(values.astype(jnp.float32), 0, n_pad)
+    ip = common.pad_to(indices.astype(jnp.int32), 0, n_pad)
+    wp = common.pad_to(w.astype(jnp.float32).reshape(d, 1), 0, d_pad)
+    m = K.margins_pallas(wp, vp, ip, block_rows=MARGINS_BLOCK_ROWS,
+                         d_block=d_pad, interpret=interpret)
+    return m[:n, 0]
+
+
+@common.register_kernel("glm_sparse_margins", common.PALLAS_TPU,
+                        caps=_MARGINS_CAPS)
+def _margins_tpu(w, values, indices):
+    return _margins_pallas(w, values, indices, interpret=False)
+
+
+@common.register_kernel("glm_sparse_margins", common.PALLAS_INTERPRET,
+                        caps=_MARGINS_CAPS)
+def _margins_interpret(w, values, indices):
+    return _margins_pallas(w, values, indices, interpret=True)
+
+
+@common.register_kernel(
+    "glm_sparse_margins", common.REFERENCE,
+    caps=common.Caps(dtypes=None, sparse=True),
+)
+@jax.jit
+def _margins_reference(w, values, indices):
+    return R.ell_margins_ref(w.astype(jnp.float32),
+                             values.astype(jnp.float32), indices)
+
+
+def ell_margins(
+    w: jax.Array,        # [d]
+    values: jax.Array,   # [N, K]  zero-padded ELL
+    indices: jax.Array,  # [N, K]  int32
+    *,
+    backend: str | None = None,
+) -> jax.Array:
+    """``[N]`` float32 margins ``x_i . w`` via the best available backend.
+
+    The one-hot gather is exact (one non-zero term a sum), so the Pallas
+    flavors differ from the XLA gather only in the order of each row's
+    sum over its K entries.
+    """
+    n, kk = values.shape
+    info = {"dtype": jnp.result_type(values).name, "sparse": True,
+            "n": n, "d": w.shape[0], "k": kk}
+    return common.dispatch("glm_sparse_margins", w, values, indices,
+                           backend=backend, info=info)

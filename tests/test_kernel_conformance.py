@@ -26,11 +26,11 @@ from repro.kernels.glm_sgd_sparse import ell_sgd_epoch
 from repro.kernels.glm_sgd_sparse.ref import ell_sgd_epoch_ref
 from repro.kernels.glm_score import glm_score
 from repro.kernels.glm_score.ref import glm_score_ref
-from repro.kernels.glm_sparse import ell_glm_grad
-from repro.kernels.glm_sparse.ref import ell_glm_grad_ref
+from repro.kernels.glm_sparse import ell_glm_grad, ell_margins
+from repro.kernels.glm_sparse.ref import ell_glm_grad_ref, ell_margins_ref
 
 FAMILIES = ("flash_attn", "glm_grad", "glm_score", "glm_sgd",
-            "glm_sgd_sparse", "glm_sparse")
+            "glm_sgd_sparse", "glm_sparse", "glm_sparse_margins")
 DTYPES = (jnp.float32, jnp.bfloat16)
 TASKS = ("lr", "svm")
 
@@ -289,6 +289,29 @@ def test_glm_sparse_conformance(backend, dtype, task, ell_data):
     out = ell_glm_grad(task, w, values, indices, y, backend=backend,
                        block_rows=8, d_block=128)
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=2e-3)
+
+
+@pytest.mark.parametrize("backend",
+                         common.available_backends("glm_sparse_margins"))
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("n", [64, 61])   # 61: the last row tile is filler
+def test_glm_sparse_margins_conformance(backend, dtype, n, ell_data):
+    values, indices, _, w = ell_data(n, 300, 8, dtype)
+    ref = ell_margins_ref(*_f32(w, values), indices)
+    out = ell_margins(w, values, indices, backend=backend)
+    assert out.shape == (n,) and out.dtype == jnp.float32
+    np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+
+def test_glm_sparse_margins_caps_route_over_budget_to_reference():
+    """The whole model is one d-block: a one-hot too large for VMEM
+    routes the margins to the oracle."""
+    info = {"dtype": "float32", "sparse": True, "n": 64, "d": 4096, "k": 8}
+    assert (common.resolve_backend("glm_sparse_margins", info=info)
+            == common.REFERENCE)
+    small = dict(info, d=300)
+    assert (common.resolve_backend("glm_sparse_margins", info=small)
+            != common.REFERENCE)
 
 
 # ---------------------------------------------------------------------------

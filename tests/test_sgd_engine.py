@@ -151,3 +151,100 @@ def test_access_path_changes_assignment_not_semantics(ds):
         res = sgd.run(prob, sgd.AsyncLocalSGD(replicas=4, local_batch=8,
                                               access=access), 6)
         assert res.losses[-1] < res.losses[0]
+
+
+def _ell_problem(task, n, d, k, padding, seed=0):
+    """ELL rows of ``k`` entries; with ``padding`` each row keeps 1..k of
+    them and pads the rest at index 0 with value 0."""
+    from repro.core import sparse
+
+    rng = np.random.default_rng(seed)
+    values = rng.standard_normal((n, k)).astype(np.float32)
+    indices = rng.integers(0, d, (n, k)).astype(np.int32)
+    if padding:
+        pad = np.arange(k)[None, :] >= rng.integers(1, k + 1, (n, 1))
+        values[pad], indices[pad] = 0.0, 0
+    y = np.where(rng.random(n) < 0.5, -1.0, 1.0).astype(np.float32)
+    ell = sparse.ELLMatrix(jnp.asarray(values), jnp.asarray(indices), d)
+    w = jnp.asarray(rng.standard_normal(d).astype(np.float32))
+    return (task, ell, jnp.asarray(y), 0.05), w
+
+
+def _moved(before, prefix):
+    from repro.obs import metrics
+
+    after = metrics.snapshot()["counters"]
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if k.startswith(prefix) and v != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("padding", [False, True])
+@pytest.mark.parametrize("n", [64, 70])  # 70: not a multiple of the row tile
+@pytest.mark.parametrize("task", ["lr", "svm"])
+def test_sparse_kernel_loss_matches_gather_loss(task, n, padding):
+    """With a kernel backend the sparse loss takes its margins from the
+    registry's glm_sparse_margins and equals sparse.loss: the launch's
+    filler rows are left out of the sum."""
+    from repro.core import sparse
+    from repro.kernels.glm_sparse import ops
+    from repro.obs import metrics
+
+    assert (n % ops.MARGINS_BLOCK_ROWS == 0) == (n == 64)
+    prob, w = _ell_problem(task, n, 128, 9, padding, seed=n)
+    before = dict(metrics.snapshot()["counters"])
+    _, _, loss_fn, _ = sgd.make_epoch_fn(
+        prob, sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"),
+        sparse_data=True)
+    got = float(loss_fn(w))
+    want = float(sparse.loss(task, prob[1], prob[2], w))
+    assert got == pytest.approx(want, rel=1e-6)
+    assert _moved(before, "kernel.backend.glm_sparse_margins.") == {
+        "kernel.backend.glm_sparse_margins.pallas-interpret": 1}
+
+
+def test_async_kernel_loss_takes_the_margins_kernel():
+    """AsyncLocalSGD passes its kernel backend to the loss too; the loss
+    reads replica 0."""
+    from repro.core import sparse
+    from repro.obs import metrics
+
+    prob, w = _ell_problem("lr", 64, 128, 9, True, seed=3)
+    before = dict(metrics.snapshot()["counters"])
+    _, _, loss_fn, _ = sgd.make_epoch_fn(
+        prob, sgd.AsyncLocalSGD(replicas=4, local_batch=8,
+                                kernel_backend="pallas-interpret"),
+        sparse_data=True)
+    W = jnp.stack([w, 2 * w, -w, 0 * w])
+    assert float(loss_fn(W)) == pytest.approx(
+        float(sparse.loss("lr", prob[1], prob[2], w)), rel=1e-6)
+    assert _moved(before, "kernel.backend.glm_sparse_margins.") == {
+        "kernel.backend.glm_sparse_margins.pallas-interpret": 1}
+
+
+@pytest.mark.parametrize("strategy,d", [
+    (sgd.SyncSGD(batch=8), 128),                        # XLA engine
+    (sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"), 4096),
+])
+def test_sparse_loss_stays_the_gather_without_a_kernel(monkeypatch,
+                                                       strategy, d):
+    """No kernel backend, or a model too wide for the one-hot: the loss
+    is sparse.loss and the margins kernel is never resolved."""
+    from repro.core import sparse
+    from repro.obs import metrics
+
+    calls = []
+    gather_loss = sparse.loss
+
+    def spy(*args):
+        calls.append(args[0])
+        return gather_loss(*args)
+
+    monkeypatch.setattr(sparse, "loss", spy)
+    prob, w = _ell_problem("svm", 16, d, 9, True, seed=5)
+    before = dict(metrics.snapshot()["counters"])
+    _, _, loss_fn, _ = sgd.make_epoch_fn(prob, strategy, sparse_data=True)
+    got = float(loss_fn(w))
+    assert calls == ["svm"]
+    assert got == pytest.approx(float(gather_loss("svm", prob[1], prob[2], w)),
+                                rel=1e-6)
+    assert _moved(before, "kernel.backend.glm_sparse_margins.") == {}

@@ -104,6 +104,21 @@ def test_glm_sparse_compiles_at_w8a_shape(shape, k):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sparse_kernel_loss_compiles_at_w8a_shape(shape):
+    """The engine's kernel-backed sparse loss: the margins launch, the
+    link and the sum in one program, at the benchmark's w8a shape."""
+    from repro.core import sparse
+
+    n, d, k = 64_696, W8A[1], 114
+    margins = common.implementation("glm_sparse_margins", common.PALLAS_TPU)
+
+    def loss(w, values, indices, y):
+        return sparse.margin_loss("lr", y * margins(w, values, indices))
+
+    compiled = jax.jit(loss).lower(*_ell(shape, n, d, k)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("k", [69, 114])
 def test_glm_score_compiles_at_w8a_width(shape, k):
     w, values, indices, _ = _ell(shape, 256, W8A[1], k)
