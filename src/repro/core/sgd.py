@@ -65,7 +65,8 @@ class SyncSGD:
     ``reference`` — see DESIGN.md §3) instead of the inline XLA
     expressions; None keeps the pure-XLA path.  Dense data supports any
     batch size (full-batch → glm_grad, mini-batch → glm_sgd); sparse
-    data supports full-batch only (glm_sparse).
+    data: full-batch → glm_sparse, mini-batch → glm_sgd_sparse, or
+    glm_sgd_csr on CSR rows too wide for the one-hot (make_epoch_fn).
     """
 
     batch: int | None = None  # None -> full batch (B = N)
@@ -232,6 +233,39 @@ def _bind(fn, data):
     return jax.tree_util.Partial(jax.jit(fn), data)
 
 
+def _onehot_fits(micro_batch: int | None, d: int, width: int) -> bool:
+    """Do the one-hot kernels' tiles over ``d`` features and rows of
+    ``width`` nonzeros fit VMEM: the loss's margins launch's, and the
+    fused epoch's at ``micro_batch`` rows (None: no fused epoch)?"""
+    from repro.kernels import common
+    from repro.kernels.glm_sparse.ops import margins_fit
+
+    return margins_fit(d, width) and (
+        micro_batch is None
+        or common.onehot_fits(micro_batch, common.padded(d, common.LANE),
+                              width))
+
+
+def _sparse_rows(m, strategy):
+    """The layout the sparse epoch and loss run on: ELL rows as given; CSR
+    rows as given where the CSR kernels take them, padded to ELL where
+    the one-hot fits or no kernel backend is asked for."""
+    if not isinstance(m, sparse.CSRRows):
+        return m
+    n = m.shape[0]
+    mini = isinstance(strategy, SyncSGD) and (strategy.batch or n) < n
+    if (strategy.kernel_backend is None
+            or _onehot_fits(strategy.batch if mini else None, m.d, m.width)):
+        return m.to_ell()
+    if not mini:
+        raise ValueError(
+            f"CSR rows too wide for the one-hot kernels (d={m.d}, widest "
+            f"row {m.width}) train only by mini-batch SyncSGD on the CSR "
+            f"kernel glm_sgd_csr; {strategy.name} would need a CSR "
+            f"sum-gradient or replica kernel, which the registry lacks")
+    return m
+
+
 def make_epoch_fn(
     problem: glm.GLMProblem | tuple,
     strategy,
@@ -253,16 +287,29 @@ def make_epoch_fn(
     the step statically (it is a kernel compile-time constant) and refuse
     ``step_param``.
 
-    With a ``kernel_backend``, the sparse loss takes its margins from the
-    registry's ``glm_sparse_margins`` on that backend where its one-hot
-    fits the VMEM budget, and from the XLA gather of :func:`sparse.loss`
-    otherwise.
+    Sparse problems come as ``(task, rows, y, step)`` with ``rows`` an
+    :class:`sparse.ELLMatrix` or :class:`sparse.CSRRows`.  ELL rows take
+    the one-hot kernels (``glm_sgd_sparse``, ``glm_sparse``) with a
+    ``kernel_backend`` and the loss takes its margins from
+    ``glm_sparse_margins`` where that one-hot fits the VMEM budget and
+    from the XLA gather of :func:`sparse.loss` where it does not.  CSR
+    rows are routed by :func:`_onehot_fits`: where the one-hot fits they
+    are padded to ELL and take that same path; where it does not, a
+    mini-batch ``SyncSGD`` with a ``kernel_backend`` trains on the CSR
+    kernels, the epoch on ``glm_sgd_csr`` and the loss's margins on
+    ``glm_csr_margins`` (full-batch and ``AsyncLocalSGD`` have no CSR
+    kernel and raise ``ValueError``).  Without a ``kernel_backend`` CSR
+    rows are padded to ELL for the XLA path.
     """
     if sparse_data:
         task, m, y, step0 = problem
+        m = _sparse_rows(m, strategy)
         n, d = m.shape
-        data = (m.values, m.indices, y)
+        csr = isinstance(m, sparse.CSRRows)
+        data = ((m.offsets, m.indices, m.values, y) if csr
+                else (m.values, m.indices, y))
     else:
+        csr = False
         task, X, y, step0 = problem.task, problem.X, problem.y, problem.step
         n, d = X.shape
         data = (X, y)
@@ -279,12 +326,19 @@ def make_epoch_fn(
 
     backend = strategy.kernel_backend
     kernel_loss = False
-    if sparse_data and backend is not None:
+    if csr:
+        from repro.kernels.glm_sgd_csr import csr_margins, csr_sgd_epoch
+    elif sparse_data and backend is not None:
         from repro.kernels.glm_sparse.ops import ell_margins, margins_fit
 
         kernel_loss = margins_fit(d, m.max_nnz)
 
-    if kernel_loss:
+    if csr:
+        def loss(data, w):
+            o, i, v, y = data
+            return sparse.margin_loss(
+                task, y * csr_margins(w, o, i, v, backend=backend))
+    elif kernel_loss:
         # the margins through the registry; the link and sum stay in XLA
         def loss(data, w):
             v, i, y = data
@@ -306,7 +360,15 @@ def make_epoch_fn(
                 "step_param needs kernel_backend=None (kernel epochs bake "
                 "the step size as a compile-time constant)")
 
-        if sparse_data:
+        if csr:
+            def epoch(data, w):
+                o, i, v, y = data
+                return csr_sgd_epoch(task, w, o, i, v, y, step=step0,
+                                     micro_batch=batch, backend=backend)
+
+            epoch = _bind(epoch, data)
+
+        elif sparse_data:
             if backend is not None:
                 # full-batch -> glm_sparse (sum gradient); mini-batch ->
                 # glm_sgd_sparse (fused epoch, model resident in VMEM)

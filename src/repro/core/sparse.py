@@ -13,6 +13,10 @@ variable-length rows are hostile to fixed-shape tiles, so we adopt ELL:
 with K = max nnz/row (optionally a high percentile with overflow rows split).
 The GLM margin is a gather-dot; the gradient is a scatter-add, both expressed
 with jnp.take / segment_sum so they lower to XLA gather/scatter on TPU.
+
+Rows too wide for that padding (news20: 455 nonzeros a row on average,
+16,423 at most) go to the device as ``CSRRows`` instead: row offsets and
+flat indices and values, padded only to the CSR kernels' DMA chunk.
 """
 from __future__ import annotations
 
@@ -21,6 +25,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import metrics, trace
 
 Array = jax.Array
 
@@ -109,12 +115,65 @@ class CSRMatrix(NamedTuple):
             indices[row_of[keep], pos[keep]] = self.indices[keep]
         return ELLMatrix(jnp.asarray(values), jnp.asarray(indices), self.d)
 
+    def to_rows(self) -> "CSRRows":
+        """The rows on the device as the CSR kernels take them (in order,
+        not padded to the widest row): the slots padded only to a whole
+        number of the kernels' DMA chunks, padded slots index 0 and value
+        0.  Counts the nonzeros held (``sparse.csr.nnz``) and the slots
+        (``sparse.csr.slots``)."""
+        from repro.kernels.glm_sgd_csr.kernel import CHUNK
+
+        nnz = self.nnz
+        if nnz >= 2 ** 31:
+            raise ValueError(f"{nnz} nonzeros overflow int32 row offsets")
+        with trace.span("sparse.csr.pack", n=self.n, nnz=nnz):
+            slots = max(CHUNK, nnz + (-nnz) % CHUNK)
+            indices = np.zeros(slots, np.int32)
+            values = np.zeros(slots, np.float32)
+            indices[:nnz] = self.indices
+            values[:nnz] = self.values
+            rows = CSRRows(jnp.asarray(self.indptr.astype(np.int32)),
+                           jnp.asarray(indices), jnp.asarray(values), self.d,
+                           int(self.row_nnz.max(initial=0)))
+        metrics.counter("sparse.csr.nnz").inc(nnz)
+        metrics.counter("sparse.csr.slots").inc(slots)
+        return rows
+
     def to_dense(self) -> np.ndarray:
         """Densify host-side (dense datasets and tests — O(N*d))."""
         out = np.zeros((self.n, self.d), dtype=np.float32)
         rows = np.repeat(np.arange(self.n), self.row_nnz)
         np.add.at(out, (rows, self.indices), self.values)
         return out
+
+
+class CSRRows(NamedTuple):
+    """CSR rows on the device: the layout of the CSR kernels
+    (``glm_sgd_csr``, ``glm_csr_margins``).
+
+    Row ``i`` is the slots ``offsets[i]:offsets[i+1]``.  Slots past
+    ``offsets[-1]`` are padding (index 0, value 0).  Built by
+    :meth:`CSRMatrix.to_rows`.
+    """
+
+    offsets: Array   # [N+1] int32 row extents
+    indices: Array   # [S] int32 feature ids, S >= nnz
+    values: Array    # [S] float32
+    d: int           # number of features (model dimension)
+    width: int       # nonzeros of the widest row
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.offsets.shape[0] - 1, self.d)
+
+    def to_ell(self) -> ELLMatrix:
+        """The same rows padded to the widest (a gather on the device)."""
+        start = self.offsets[:-1, None]
+        pos = start + jnp.arange(max(self.width, 1), dtype=jnp.int32)[None, :]
+        keep = pos < self.offsets[1:, None]
+        pos = jnp.where(keep, pos, 0)
+        return ELLMatrix(jnp.where(keep, self.values[pos], 0.0),
+                         jnp.where(keep, self.indices[pos], 0), self.d)
 
 
 def from_csr_parts(

@@ -24,5 +24,6 @@ from repro.kernels.flash_attn import flash_attention  # noqa: F401
 from repro.kernels.glm_grad import glm_grad  # noqa: F401
 from repro.kernels.glm_score import glm_score  # noqa: F401
 from repro.kernels.glm_sgd import glm_sgd_epoch  # noqa: F401
+from repro.kernels.glm_sgd_csr import csr_margins, csr_sgd_epoch  # noqa: F401
 from repro.kernels.glm_sgd_sparse import ell_sgd_epoch  # noqa: F401
 from repro.kernels.glm_sparse import ell_glm_grad  # noqa: F401

@@ -317,6 +317,18 @@ def onehot_fits(rows: int, d: int, k: int) -> bool:
     return size <= ONEHOT_VMEM_BYTES
 
 
+def check_onehot(kernel: str, rows: int, d: int, k: int) -> None:
+    """Raise the ``ValueError`` a forced one-hot Pallas flavor gives a
+    tile whose one-hot does not fit :data:`ONEHOT_VMEM_BYTES`."""
+    if not onehot_fits(rows, d, k):
+        size = rows * padded(max(d, 1), SUBLANE) * padded(max(k, 1), LANE) * 4
+        raise ValueError(
+            f"{kernel}'s fp32 one-hot [{rows}, {d}, {k}] takes {size:,} "
+            f"bytes of VMEM, over its budget of {ONEHOT_VMEM_BYTES:,}; "
+            f"train rows this wide on the CSR path (sparse.CSRRows, "
+            f"glm_sgd_csr / glm_csr_margins)")
+
+
 def ell_onehot(idx: jax.Array, d: int) -> jax.Array:
     """One-hot ``[R, d, K]`` fp32 of an ELL index tile ``[R, K]``.
 

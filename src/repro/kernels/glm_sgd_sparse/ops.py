@@ -9,8 +9,9 @@ oracle: the micro-batch tiling rule (:func:`repro.kernels.common.
 micro_batch_ok`: a divisor of n that is a multiple of 8 or n itself) and
 the one-hot VMEM budget (:func:`repro.kernels.common.onehot_fits`; the
 one-hot spans the full padded feature axis because the model never
-leaves VMEM).  Forcing a Pallas flavor past the tiling rule raises
-``ValueError``.  When the caller does not pin ``micro_batch``, the
+leaves VMEM).  Forcing a Pallas flavor past either raises
+``ValueError``; rows too wide for the one-hot train on the CSR path
+(``glm_sgd_csr``).  When the caller does not pin ``micro_batch``, the
 per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
 before the built-in default.
 """
@@ -48,13 +49,15 @@ _PALLAS_CAPS = common.Caps(sparse=True, check=_caps_check)
 def _pallas(task, w, values, indices, y, *, step, micro_batch, interpret):
     """One fused sparse SGD epoch; model stays in VMEM throughout.
 
-    ``micro_batch`` must obey the tiling rule (checked, ValueError); d is
-    padded to the 128-lane tile internally.
+    ``micro_batch`` must obey the tiling rule and the one-hot must fit
+    its VMEM budget (both checked, ValueError); d is padded to the
+    128-lane tile internally.
     """
     n, _ = values.shape
     d = w.shape[0]
     common.check_micro_batch("glm_sgd_sparse", n, micro_batch)
     d_pad = common.padded(d, common.LANE)
+    common.check_onehot("glm_sgd_sparse", micro_batch, d_pad, values.shape[1])
     vp = values.astype(jnp.float32)
     ip = indices.astype(jnp.int32)
     yp = y.astype(jnp.float32).reshape(n, 1)

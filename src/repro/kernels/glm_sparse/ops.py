@@ -160,12 +160,15 @@ def _margins_pallas(w, values, indices, *, interpret):
     """One margins launch over row tiles, the model pinned as one d-block.
 
     N is zero-padded up to the row tile and the filler rows sliced off
-    (a zero row's margin is 0, which is not a zero loss).
+    (a zero row's margin is 0, which is not a zero loss).  A one-hot
+    over VMEM's budget raises ``ValueError`` (:func:`margins_fit`).
     """
     n, _ = values.shape
     d = w.shape[0]
     n_pad = common.padded(n, MARGINS_BLOCK_ROWS)
     d_pad = common.padded(d, common.LANE)
+    common.check_onehot("glm_sparse_margins", MARGINS_BLOCK_ROWS, d_pad,
+                        values.shape[1])
     vp = common.pad_to(values.astype(jnp.float32), 0, n_pad)
     ip = common.pad_to(indices.astype(jnp.int32), 0, n_pad)
     wp = common.pad_to(w.astype(jnp.float32).reshape(d, 1), 0, d_pad)

@@ -22,6 +22,9 @@ from repro.kernels.glm_grad import glm_grad
 from repro.kernels.glm_grad.ref import glm_grad_ref
 from repro.kernels.glm_sgd import glm_sgd_epoch
 from repro.kernels.glm_sgd.ref import glm_sgd_epoch_ref
+from repro.kernels.glm_sgd_csr import csr_margins, csr_sgd_epoch
+from repro.kernels.glm_sgd_csr.kernel import CHUNK
+from repro.kernels.glm_sgd_csr.ref import csr_margins_ref, csr_sgd_epoch_ref
 from repro.kernels.glm_sgd_sparse import ell_sgd_epoch
 from repro.kernels.glm_sgd_sparse.ref import ell_sgd_epoch_ref
 from repro.kernels.glm_score import glm_score
@@ -30,7 +33,8 @@ from repro.kernels.glm_sparse import ell_glm_grad, ell_margins
 from repro.kernels.glm_sparse.ref import ell_glm_grad_ref, ell_margins_ref
 
 FAMILIES = ("flash_attn", "glm_grad", "glm_score", "glm_sgd",
-            "glm_sgd_sparse", "glm_sparse", "glm_sparse_margins")
+            "glm_sgd_sparse", "glm_sparse", "glm_sparse_margins",
+            "glm_sgd_csr", "glm_csr_margins")
 DTYPES = (jnp.float32, jnp.bfloat16)
 TASKS = ("lr", "svm")
 
@@ -312,6 +316,95 @@ def test_glm_sparse_margins_caps_route_over_budget_to_reference():
     small = dict(info, d=300)
     assert (common.resolve_backend("glm_sparse_margins", info=small)
             != common.REFERENCE)
+
+
+@pytest.mark.parametrize("kernel", ["glm_sgd_sparse", "glm_sparse_margins"])
+def test_forced_onehot_over_budget_names_the_csr_path(kernel):
+    """Forcing a one-hot Pallas flavor on rows whose one-hot does not fit
+    VMEM raises, pointing at the CSR kernels, instead of handing Mosaic
+    the one-hot."""
+    d, k = 20_000, 40
+    values = jnp.ones((16, k), jnp.float32)
+    indices = jnp.zeros((16, k), jnp.int32)
+    w = jnp.zeros(d, jnp.float32)
+    with pytest.raises(ValueError, match="CSR path"):
+        if kernel == "glm_sgd_sparse":
+            ell_sgd_epoch("lr", w, values, indices, jnp.ones(16), step=0.1,
+                          micro_batch=8, backend=common.PALLAS_INTERPRET)
+        else:
+            ell_margins(w, values, indices, backend=common.PALLAS_INTERPRET)
+
+
+# ---------------------------------------------------------------------------
+# glm_sgd_csr / glm_csr_margins: CSR rows, gather and scatter by index
+# ---------------------------------------------------------------------------
+
+
+def _csr_rows(seed, n=64, d=5_000):
+    """Heavy-tailed CSR rows: log-normal widths clipped to 1..700, one row
+    wider than a DMA chunk, and rows 0 and 1 (one micro-batch) sharing
+    three features; d is not a multiple of the 128 lanes."""
+    from repro.core import sparse
+
+    rng = np.random.default_rng(seed)
+    widths = np.clip(rng.lognormal(4.0, 1.2, n), 1, 700).astype(np.int64)
+    widths[5] = CHUNK + 76
+    widths[:2] = np.maximum(widths[:2], 3)
+    rows = [rng.choice(d, k, replace=False).astype(np.int32) for k in widths]
+    rows[1][:3] = rows[0][:3]
+    vals = [rng.standard_normal(k).astype(np.float32) for k in widths]
+    y = np.where(rng.random(n) < 0.5, -1.0, 1.0).astype(np.float32)
+    w = (0.1 * rng.standard_normal(d)).astype(np.float32)
+    m = sparse.from_csr_parts(rows, vals, d).to_rows()
+    return m, jnp.asarray(y), jnp.asarray(w)
+
+
+#: float32 either way, the sums in another order: the kernel adds a row's
+#: products lane by lane and then across the tile, the oracle by segment
+#: sum; rows of up to 1,100 products agree to about 1e-6 of their size
+CSR_RTOL, CSR_ATOL = 1e-5, 1e-5
+
+
+@pytest.mark.parametrize("backend", common.available_backends("glm_sgd_csr"))
+@pytest.mark.parametrize("task", TASKS)
+def test_glm_sgd_csr_conformance(backend, task):
+    m, y, w = _csr_rows(seed=11)
+    ref = csr_sgd_epoch_ref(task, w, m.offsets, m.indices, m.values, y, 0.5, 8)
+    out = csr_sgd_epoch(task, w, m.offsets, m.indices, m.values, y, step=0.5,
+                        micro_batch=8, backend=backend)
+    assert out.shape == w.shape and out.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(ref - w))) > 0.05     # the epoch moved w
+    np.testing.assert_allclose(out, ref, rtol=CSR_RTOL, atol=CSR_ATOL)
+
+
+@pytest.mark.parametrize("backend",
+                         common.available_backends("glm_csr_margins"))
+@pytest.mark.parametrize("n", [64, 61])   # 61: the last block is filler
+def test_glm_csr_margins_conformance(backend, n):
+    m, _, w = _csr_rows(seed=n, n=n)
+    ref = csr_margins_ref(w, m.offsets, m.indices, m.values)
+    out = csr_margins(w, m.offsets, m.indices, m.values, backend=backend)
+    assert out.shape == (n,) and out.dtype == jnp.float32
+    dense = np.asarray(sparse_to_dense(m)) @ np.asarray(w, np.float64)
+    np.testing.assert_allclose(ref, dense, rtol=CSR_RTOL, atol=CSR_ATOL)
+    np.testing.assert_allclose(out, ref, rtol=CSR_RTOL, atol=CSR_ATOL)
+
+
+def sparse_to_dense(m):
+    """``[N, d]`` float64 of CSR rows, from the host arrays."""
+    off = np.asarray(m.offsets)
+    X = np.zeros((len(off) - 1, m.d))
+    rows = np.repeat(np.arange(len(off) - 1), np.diff(off))
+    np.add.at(X, (rows, np.asarray(m.indices)[:off[-1]]),
+              np.asarray(m.values)[:off[-1]])
+    return X
+
+
+def test_glm_sgd_csr_refuses_untileable_micro_batch():
+    m, y, w = _csr_rows(seed=3)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        csr_sgd_epoch("lr", w, m.offsets, m.indices, m.values, y, step=0.5,
+                      micro_batch=4, backend=common.PALLAS_INTERPRET)
 
 
 # ---------------------------------------------------------------------------

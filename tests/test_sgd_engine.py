@@ -248,3 +248,104 @@ def test_sparse_loss_stays_the_gather_without_a_kernel(monkeypatch,
     assert got == pytest.approx(float(gather_loss("svm", prob[1], prob[2], w)),
                                 rel=1e-6)
     assert _moved(before, "kernel.backend.glm_sparse_margins.") == {}
+
+
+def _csr_problem(task, n, d, seed=0, widest=300):
+    """Heavy-tailed CSR rows (3..``widest`` nonzeros, rows 0 and 1
+    sharing features) as the engine takes them, and the same rows as
+    ELL."""
+    from repro.core import sparse
+
+    rng = np.random.default_rng(seed)
+    widths = np.clip(rng.lognormal(3.5, 1.2, n), 3, widest).astype(np.int64)
+    rows = [rng.choice(d, k, replace=False).astype(np.int32) for k in widths]
+    rows[1][:3] = rows[0][:3]
+    vals = [rng.standard_normal(k).astype(np.float32) for k in widths]
+    host = sparse.from_csr_parts(rows, vals, d)
+    y = jnp.asarray(np.where(rng.random(n) < 0.5, -1.0, 1.0), jnp.float32)
+    return (task, host.to_rows(), y, 0.3), host.to_ell()
+
+
+@pytest.mark.parametrize("task", ["lr", "svm"])
+def test_csr_engine_matches_the_ell_oracle(task):
+    """CSR rows too wide for the one-hot train on glm_sgd_csr with the
+    loss on glm_csr_margins, and give the plain ELL oracle's models and
+    losses over 3 epochs; no kernel falls back."""
+    from repro.core import sparse
+    from repro.obs import metrics
+
+    prob, ell = _csr_problem(task, 64, 5_000, seed=7)
+    before = dict(metrics.snapshot()["counters"])
+    init, epoch_fn, loss_fn, _ = sgd.make_epoch_fn(
+        prob, sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"),
+        sparse_data=True)
+    w, want = init, init
+    for _ in range(3):
+        w = epoch_fn(w)
+        want = sparse.minibatch_epoch(task, want, ell, prob[2], 0.3, 8)
+        np.testing.assert_allclose(w, want, rtol=1e-5, atol=1e-6)
+        assert float(loss_fn(w)) == pytest.approx(
+            float(sparse.loss(task, ell, prob[2], want)), rel=1e-5)
+    assert float(jnp.linalg.norm(w)) > 0.1
+    assert _moved(before, "kernel.backend.") == {
+        "kernel.backend.glm_sgd_csr.pallas-interpret": 1,
+        "kernel.backend.glm_csr_margins.pallas-interpret": 1}
+    assert _moved(before, "kernel.fallback.") == {}
+
+
+def test_csr_rows_that_fit_the_onehot_take_the_ell_kernels():
+    """Where the one-hot fits, CSR rows are padded to ELL and take the
+    one-hot families, with the ELL losses."""
+    from repro.core import sparse
+    from repro.obs import metrics
+
+    prob, ell = _csr_problem("lr", 16, 200, seed=2, widest=20)
+    before = dict(metrics.snapshot()["counters"])
+    init, epoch_fn, loss_fn, _ = sgd.make_epoch_fn(
+        prob, sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"),
+        sparse_data=True)
+    w = epoch_fn(init)
+    want = sparse.minibatch_epoch("lr", init, ell, prob[2], 0.3, 8)
+    np.testing.assert_allclose(w, want, rtol=1e-5, atol=1e-6)
+    assert float(loss_fn(w)) == pytest.approx(
+        float(sparse.loss("lr", ell, prob[2], want)), rel=1e-5)
+    assert _moved(before, "kernel.backend.") == {
+        "kernel.backend.glm_sgd_sparse.pallas-interpret": 1,
+        "kernel.backend.glm_sparse_margins.pallas-interpret": 1}
+
+
+def test_w8a_shaped_ell_still_takes_the_onehot_kernels():
+    """ELL rows at w8a's width (d 300, widest row 114) resolve
+    glm_sgd_sparse and glm_sparse_margins as before the CSR kernels came,
+    and nothing falls back."""
+    from repro.obs import metrics
+
+    prob, w = _ell_problem("lr", 64, 300, 114, True, seed=8)
+    before = dict(metrics.snapshot()["counters"])
+    _, epoch_fn, loss_fn, _ = sgd.make_epoch_fn(
+        prob, sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"),
+        sparse_data=True)
+    float(loss_fn(epoch_fn(w)))
+    assert _moved(before, "kernel.backend.") == {
+        "kernel.backend.glm_sgd_sparse.pallas-interpret": 1,
+        "kernel.backend.glm_sparse_margins.pallas-interpret": 1}
+    assert _moved(before, "kernel.fallback.") == {}
+
+
+@pytest.mark.parametrize("strategy", [
+    sgd.SyncSGD(kernel_backend="pallas-interpret"),
+    sgd.AsyncLocalSGD(replicas=4, local_batch=8,
+                      kernel_backend="pallas-interpret"),
+])
+def test_csr_rows_too_wide_refuse_strategies_without_a_csr_kernel(strategy):
+    prob, _ = _csr_problem("lr", 64, 5_000, seed=1)
+    with pytest.raises(ValueError, match="glm_sgd_csr"):
+        sgd.make_epoch_fn(prob, strategy, sparse_data=True)
+
+
+def test_csr_rows_run_through_sgd_run():
+    """``sgd.run`` takes the CSR problem as it is; the loss falls."""
+    prob, _ = _csr_problem("lr", 64, 5_000, seed=4)
+    res = sgd.run(prob, sgd.SyncSGD(batch=8, kernel_backend="pallas-interpret"),
+                  3, sparse_data=True, record_time=False)
+    assert res.losses[-1] < res.losses[0]

@@ -6,7 +6,9 @@ would raise (tiling rules, unsupported vector shape casts, VMEM).  The
 shapes are the full Table-3 ones — covtype 581,012 x 54 dense, w8a
 64,700 x 300 with ELL widths 69 (the generator's cap) and 114 (the
 dataset's widest row) — so a kernel that only interpret mode accepts
-fails here, on the CPU, before any chip time is spent.
+fails here, on the CPU, before any chip time is spent.  news20
+(19,992 x 1,355,191, ~9.1M nonzeros) compiles on the CSR kernels, whose
+model is 5.4 MB of VMEM.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every test worker
@@ -24,6 +26,10 @@ from repro.kernels import common
 
 COVTYPE = (581_012, 54)
 W8A = (64_700, 300)
+#: news20.binary as the benchmark holds it: n cut to a multiple of the
+#: micro-batch, and its ~9.1M nonzeros (454.99 a row) padded to the CSR
+#: kernels' DMA chunk
+NEWS20 = (19_992, 1_355_191, 9_097_216)
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +122,35 @@ def test_sparse_kernel_loss_compiles_at_w8a_shape(shape):
         return sparse.margin_loss("lr", y * margins(w, values, indices))
 
     compiled = jax.jit(loss).lower(*_ell(shape, n, d, k)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _csr(shape, n, d, slots):
+    return (shape((d,)), shape((n + 1,), jnp.int32),
+            shape((slots,), jnp.int32), shape((slots,)))
+
+
+def test_glm_sgd_csr_compiles_at_news20_shape(shape):
+    w, offsets, indices, values = _csr(shape, *NEWS20)
+    compiled = _compile("glm_sgd_csr", "lr",
+                        (w, offsets, indices, values, shape((NEWS20[0],))),
+                        step=0.5, micro_batch=8)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_csr_kernel_loss_compiles_at_news20_shape(shape):
+    """The engine's CSR loss: the margins launch, the link and the sum in
+    one program, at news20's shape."""
+    from repro.core import sparse
+
+    margins = common.implementation("glm_csr_margins", common.PALLAS_TPU)
+
+    def loss(w, offsets, indices, values, y):
+        return sparse.margin_loss(
+            "lr", y * margins(w, offsets, indices, values))
+
+    compiled = jax.jit(loss).lower(*_csr(shape, *NEWS20),
+                                   shape((NEWS20[0],))).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
