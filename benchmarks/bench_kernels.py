@@ -1,19 +1,18 @@
 """Kernel microbenchmark trajectory producer -> ``BENCH_kernels.json``.
 
-One trajectory point per (kernel family, shape, dtype, block-config
-variant): measured wall time on this host's auto-resolved backend, the
+One trajectory point per (kernel family, shape, dtype, variant):
+measured wall time on this host's auto-resolved backend, the
 conformance verdict of every dispatchable Pallas flavor against the
 family's oracle, and the analytic roofline annotation
-(``repro.roofline.kernels``).  Variants cover the family's *default*
-block geometry and the *tuned* geometry the autotuner cache picks
-(``repro.kernels.tune``); fp32 rows add a bf16-input point.
+(``repro.roofline.kernels``).  Every point runs at the family's
+built-in block geometry (variant ``default``), in float32 and with
+bfloat16 inputs.
 
 Determinism contract (same as ``BENCH_study.json``): wall times are
 cached in ``bench_results/kernel_cache`` keyed by the entry identity
-(kernel, shape, dtype, variant, backend, host, device kind), and tuning
-sweeps are cached in ``bench_results/tune_cache`` — a warm re-run reads
-both caches and writes a byte-identical ``BENCH_kernels.json``, which CI
-asserts.  The >20% regression gate (``claims.check_bench_kernels``)
+(kernel, shape, dtype, variant, backend, host, device kind) — a warm
+re-run reads the cache and writes a byte-identical
+``BENCH_kernels.json``, which CI asserts.  The >20% regression gate (``claims.check_bench_kernels``)
 compares each point against the *committed* trajectory entry with the
 same label, host, and device kind — cross-host timings never gate — and
 its baseline lookups stay out of the snapshot so the file remains a pure
@@ -33,7 +32,6 @@ import numpy as np
 from benchmarks import common
 from repro.data import synthetic
 from repro.kernels import common as kcommon
-from repro.kernels import tune
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.flash_attn.ref import attention_ref
 from repro.kernels.glm_grad import glm_grad
@@ -53,7 +51,7 @@ from repro.utils.timing import time_stats
 #: bump to invalidate every cached wall time (timing protocol changes)
 TIMING_SCHEMA = 1
 
-STEP = 0.05  # SGD-epoch step size (a compile-time constant, not tuned)
+STEP = 0.05  # SGD-epoch step size (a compile-time constant)
 
 # family -> per-profile benchmark shape
 SHAPES = {
@@ -71,11 +69,9 @@ SHAPES = {
     },
 }
 
-#: (dtype, variant) trajectory points per family; the tuned variant only
-#: makes sense where the caches can pin a winner, and bf16 tracks input-
-#: cast cost at the default geometry
-VARIANTS = (("float32", "default"), ("float32", "tuned"),
-            ("bfloat16", "default"))
+#: (dtype, variant) trajectory points per family; bf16 tracks input-cast
+#: cost at the default geometry
+VARIANTS = (("float32", "default"), ("bfloat16", "default"))
 
 
 def _digest(obj) -> str:
@@ -90,8 +86,8 @@ class _Family:
     """One family's benchmark closure set at a concrete shape + dtype."""
 
     def __init__(self, info, call, oracle, tol):
-        self.info = info          # dispatch/tuner/roofline call info
-        self.call = call          # call(backend=..., **cfg) -> jax value
+        self.info = info          # dispatch/roofline call info
+        self.call = call          # call(backend=...) -> jax value
         self.oracle = oracle      # oracle() -> reference output
         self.tol = tol            # (rtol, atol) for the conformance check
 
@@ -110,13 +106,13 @@ def _make_family(kernel: str, shape: dict, dtype: str) -> _Family:
         w = jnp.asarray(rng.normal(0, 0.1, d), dtype=jdt)
         info = {"dtype": dtype, "n": n, "d": d}
         if kernel == "glm_grad":
-            call = lambda backend=None, **cfg: glm_grad(  # noqa: E731
-                "lr", w, X, y, backend=backend, **cfg)
+            call = lambda backend=None: glm_grad(  # noqa: E731
+                "lr", w, X, y, backend=backend)
             oracle = lambda: glm_grad_ref(  # noqa: E731
                 "lr", *(a.astype(jnp.float32) for a in (w, X, y)))
         else:
-            call = lambda backend=None, **cfg: glm_sgd_epoch(  # noqa: E731
-                "lr", w, X, y, step=STEP, backend=backend, **cfg)
+            call = lambda backend=None: glm_sgd_epoch(  # noqa: E731
+                "lr", w, X, y, step=STEP, backend=backend)
             oracle = lambda: glm_sgd_epoch_ref(  # noqa: E731
                 "lr", *(a.astype(jnp.float32) for a in (w, X, y)), STEP, 8)
             info["micro_batch"] = 8  # oracle comparison fixes the default
@@ -134,12 +130,12 @@ def _make_family(kernel: str, shape: dict, dtype: str) -> _Family:
         f32 = lambda: (w.astype(jnp.float32), vals.astype(jnp.float32),  # noqa: E731
                        idx, y.astype(jnp.float32))
         if kernel == "glm_sparse":
-            call = lambda backend=None, **cfg: ell_glm_grad(  # noqa: E731
-                "lr", w, vals, idx, y, backend=backend, **cfg)
+            call = lambda backend=None: ell_glm_grad(  # noqa: E731
+                "lr", w, vals, idx, y, backend=backend)
             oracle = lambda: ell_glm_grad_ref("lr", *f32())  # noqa: E731
         else:
-            call = lambda backend=None, **cfg: ell_sgd_epoch(  # noqa: E731
-                "lr", w, vals, idx, y, step=STEP, backend=backend, **cfg)
+            call = lambda backend=None: ell_sgd_epoch(  # noqa: E731
+                "lr", w, vals, idx, y, step=STEP, backend=backend)
             oracle = lambda: ell_sgd_epoch_ref(  # noqa: E731
                 "lr", *f32(), STEP, 8)
             info["micro_batch"] = 8
@@ -153,8 +149,8 @@ def _make_family(kernel: str, shape: dict, dtype: str) -> _Family:
     v = jnp.asarray(rng.normal(0, 1, (b, hkv, sk, hd)), dtype=jdt)
     info = {"dtype": dtype, "head_dim": hd, "seq_q": sq, "seq_k": sk,
             **shape}
-    call = lambda backend=None, **cfg: flash_attention(  # noqa: E731
-        q, kk, v, causal=True, backend=backend, **cfg)
+    call = lambda backend=None: flash_attention(  # noqa: E731
+        q, kk, v, causal=True, backend=backend)
     rep = hq // hkv
     oracle = lambda: attention_ref(  # noqa: E731
         q.astype(jnp.float32),
@@ -201,9 +197,8 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_kernels.json"):
     store = KernelBenchStore(
         out_json, jsonl_path=common.RESULTS_DIR / "kernel_runs.jsonl")
     timing_cache = TrialCache(common.RESULTS_DIR / "kernel_cache")
-    tune_cache = tune.TuneCache(common.RESULTS_DIR / "tune_cache")
     host = platform.node()
-    device_kind = tune.device_kind()
+    device_kind = kcommon.device_kind()
 
     rows = []
     for kernel, shapes in SHAPES.items():
@@ -217,20 +212,14 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_kernels.json"):
                 verdicts[dtype] = _conformance(kernel, fam)
             pallas_match, checked = verdicts[dtype]
 
-            config: dict = {}
-            if variant == "tuned":
-                config = dict(tune.tune(kernel, backend, fam.info, fam.call,
-                                        cache=tune_cache)["config"])
-
             label = f"{kernel}/{tag}/{dtype}/{variant}"
             key = _digest({"timing_schema": TIMING_SCHEMA, "label": label,
                            "profile": profile, "backend": backend,
-                           "config": config, "host": host,
+                           "config": {}, "host": host,
                            "device_kind": device_kind})
             payload = timing_cache.peek(key)
             if payload is None:
-                stats = time_stats(lambda: fam.call(**config),
-                                   warmup=1, iters=5)
+                stats = time_stats(fam.call, warmup=1, iters=5)
                 # the snapshot commits only the median (deterministic via
                 # the timing cache); dispersion goes to the JSONL sidecar
                 payload = {"wall_s": stats["median"]}
@@ -246,7 +235,7 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_kernels.json"):
                 "dtype": dtype,
                 "variant": variant,
                 "backend": backend,
-                "config": config,
+                "config": {},   # the family's built-in geometry
                 "wall_s": payload["wall_s"],
                 "pallas_match": pallas_match,
                 "checked_backends": checked,

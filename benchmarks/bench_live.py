@@ -51,7 +51,7 @@ import time
 import numpy as np
 
 from benchmarks import common
-from repro.kernels import tune
+from repro.kernels import common as kcommon
 from repro.live import (LiveConfig, LiveLearner, SnapshotPublisher,
                         SyntheticStream)
 from repro.obs import metrics, trace
@@ -260,7 +260,7 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_live.json",
         out_json, jsonl_path=common.RESULTS_DIR / "live_runs.jsonl")
     timing_cache = TrialCache(common.RESULTS_DIR / "live_cache")
     host = platform.node()
-    device_kind = tune.device_kind()
+    device_kind = kcommon.device_kind()
 
     cfg = PROFILES[profile]
     rows = []

@@ -50,7 +50,6 @@ import numpy as np
 from benchmarks import common
 from repro.data import synthetic
 from repro.kernels import common as kcommon
-from repro.kernels import tune
 from repro.kernels.glm_score import glm_score
 from repro.kernels.glm_score.ref import glm_score_ref
 from repro.obs import metrics, trace
@@ -216,7 +215,7 @@ def run(profile: str = "ci", *, out_json: str = "BENCH_serve.json",
         out_json, jsonl_path=common.RESULTS_DIR / "serve_runs.jsonl")
     timing_cache = TrialCache(common.RESULTS_DIR / "serve_cache")
     host = platform.node()
-    device_kind = tune.device_kind()
+    device_kind = kcommon.device_kind()
 
     cfg = PROFILES[profile]
     n_req, d = cfg["n_requests"], cfg["d"]

@@ -326,7 +326,7 @@ def main() -> int:
         print(f"chip_smoke: needs a TPU; JAX found {backend!r}",
               file=sys.stderr)
         return 1
-    from repro.kernels import tune
+    from repro.kernels import common
     from repro.roofline import analysis
     from repro.utils import compile_cache
 
@@ -335,7 +335,7 @@ def main() -> int:
     # inputs to bf16, which would compare rounding instead of kernels
     jax.config.update("jax_default_matmul_precision", "highest")
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
-    kind = tune.device_kind()
+    kind = common.device_kind()
     print(f"chip_smoke: {jax.devices()[0].device_kind} x{len(jax.devices())}"
           f" ({kind}: peaks {analysis.peaks(kind)}), compile cache "
           f"{cache_dir}", flush=True)

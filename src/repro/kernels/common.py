@@ -14,14 +14,17 @@ analogue here is a three-backend registry per kernel family:
 
 Selection order (``resolve_backend``):
 
-1. explicit call-site forcing: a ``backend=`` argument, or the legacy
-   ``interpret=`` / ``force_path=`` flags;
+1. the call site's ``backend=`` argument;
 2. the ``REPRO_KERNEL_BACKEND`` environment variable (global override —
    e.g. ``REPRO_KERNEL_BACKEND=reference`` to take Pallas entirely out
    of the picture when bisecting a numerics issue);
 3. auto: the first backend of ``pallas-tpu`` → ``pallas-interpret`` →
    ``reference`` that is available on this host AND whose capability
    flags accept the call (dtype, sparsity, shape budgets).
+
+A call that pins no tile runs at its family's built-in one: a
+``DEFAULT_*`` constant, or a rule on the shape, in the family's
+``ops.py``.
 
 Explicit and env overrides bypass the *capability* heuristics (forcing
 is on you) but still fail fast on hard unavailability: ``pallas-tpu``
@@ -129,6 +132,16 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def device_kind() -> str:
+    """Normalized accelerator model string of the first device.
+
+    Raises whatever JAX raises when no backend can start: a record keyed
+    to a made-up device would be read as the wrong hardware's.
+    """
+    kind = jax.devices()[0].device_kind
+    return str(kind).strip().lower().replace(" ", "-")
+
+
 def _host_available(backend: str) -> bool:
     """Hard availability: can this backend run on the current host at all?"""
     return backend != PALLAS_TPU or on_tpu()
@@ -153,23 +166,15 @@ def resolve_backend(
     kernel: str,
     *,
     backend: str | None = None,
-    interpret: bool | None = None,
     info: dict | None = None,
 ) -> str:
-    """Pick the backend for one call.  See module docstring for the order.
-
-    ``interpret`` is the legacy flag the pre-registry wrappers exposed:
-    True → ``pallas-interpret``, False → ``pallas-tpu``, None → auto.
-    Like ``backend``, it is call-site-explicit and beats the env var.
-    """
+    """Pick the backend for one call.  See module docstring for the order."""
     impls = _REGISTRY.get(kernel)
     if not impls:
         raise KeyError(f"no kernel registered under {kernel!r}; "
                        f"known: {registered_kernels()}")
 
     forced = backend
-    if forced is None and interpret is not None:
-        forced = PALLAS_INTERPRET if interpret else PALLAS_TPU
     if forced is None:
         forced = os.environ.get(ENV_BACKEND) or None
     if forced is not None:
@@ -211,7 +216,8 @@ def resolve_backend(
 
 def implementation(kernel: str, backend: str) -> Callable:
     """The registered ``backend`` flavor of ``kernel``, with no resolution
-    — for compiling one flavor for a device this host does not have."""
+    — for a caller that resolved once for a fixed call shape, or that
+    compiles one flavor for a device this host does not have."""
     return _REGISTRY[kernel][backend].fn
 
 
@@ -219,12 +225,11 @@ def dispatch(
     kernel: str,
     *args,
     backend: str | None = None,
-    interpret: bool | None = None,
     info: dict | None = None,
     **kwargs,
 ):
     """Resolve a backend and invoke the registered implementation."""
-    b = resolve_backend(kernel, backend=backend, interpret=interpret, info=info)
+    b = resolve_backend(kernel, backend=backend, info=info)
     return implementation(kernel, b)(*args, **kwargs)
 
 

@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.flash_attn import kernel as K
 from repro.kernels.flash_attn import ref as R
 
@@ -104,13 +104,11 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     backend: str | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Flash attention with GQA broadcast.  Returns [B, Hq, Sq, hd].
 
-    Unpinned ``block_q``/``block_k`` consult the autotuner cache
-    (:mod:`repro.kernels.tune`), then fall back to ``pick_block``'s
-    lane-aligned heuristic inside the Pallas wrapper.
+    Unpinned ``block_q``/``block_k`` take ``pick_block``'s sublane-aligned
+    rule inside the Pallas wrapper.
     """
     hd = q.shape[3]
     assert q.shape[1] % k.shape[1] == 0, (q.shape[1], k.shape[1])
@@ -120,20 +118,10 @@ def flash_attention(
         info["block_q"] = block_q
     if block_k is not None:
         info["block_k"] = block_k
-    b = common.resolve_backend("flash_attn", backend=backend,
-                               interpret=interpret, info=info)
-    if block_q is None and block_k is None:
-        run = None
-        if tune.timeable(q, k, v):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "flash_attn", q, k, v, causal=causal, window=window,
-                backend=b, **cfg)
-        cfg = tune.consult("flash_attn", b, info, run)
-        block_q, block_k = cfg.get("block_q"), cfg.get("block_k")
     return common.dispatch(
         "flash_attn", q, k, v, causal=causal, window=window,
         block_q=block_q, block_k=block_k,
-        backend=b, info=info,
+        backend=backend, info=info,
     )
 
 

@@ -13,7 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.glm_grad import kernel as K
 from repro.kernels.glm_grad import ref as R
 
@@ -76,24 +76,14 @@ def glm_grad(
     layout: str = "row",
     block_rows: int | None = None,
     backend: str | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Sum GLM gradient via the best available backend.  Returns [d] fp32.
 
-    ``block_rows=None`` consults the autotuner cache
-    (:mod:`repro.kernels.tune`) for this (backend, device, shape-class);
-    with no cached winner the kernel's built-in heuristic applies.
+    ``block_rows=None`` takes the kernel's built-in rule: N padded to
+    sublanes, at most 512 rows a block.
     """
     info = {"dtype": jnp.result_type(X).name, "n": X.shape[0], "d": X.shape[1]}
-    b = common.resolve_backend("glm_grad", backend=backend,
-                               interpret=interpret, info=info)
-    if block_rows is None:
-        run = None
-        if tune.timeable(w, X, y):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "glm_grad", task, w, X, y, layout=layout, backend=b, **cfg)
-        block_rows = tune.consult("glm_grad", b, info, run).get("block_rows")
     return common.dispatch(
         "glm_grad", task, w, X, y, layout=layout, block_rows=block_rows,
-        backend=b, info=info,
+        backend=backend, info=info,
     )

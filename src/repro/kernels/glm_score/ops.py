@@ -8,9 +8,8 @@ divisibility cap: N is zero-padded up to ``block_rows`` and the filler
 scores are sliced off.  One capability gate routes problems the one-hot
 cannot shape to the oracle: its VMEM budget
 (:func:`repro.kernels.common.onehot_fits`; the one-hot spans the full
-padded feature axis because the model never leaves VMEM).  When the
-caller does not pin ``block_rows``, the per-device autotuner cache
-(:mod:`repro.kernels.tune`) is consulted before the built-in default.
+padded feature axis because the model never leaves VMEM).  A caller
+that pins no ``block_rows`` gets ``DEFAULT_BLOCK_ROWS``.
 """
 from __future__ import annotations
 
@@ -19,11 +18,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.glm_score import kernel as K
 from repro.kernels.glm_score import ref as R
 
-#: built-in row tile when neither the caller nor the tuner pins one
+#: row tile when the caller pins none
 DEFAULT_BLOCK_ROWS = 8
 
 
@@ -98,33 +97,18 @@ def glm_score(
     values: jax.Array,   # [N, K]  zero-padded ELL
     indices: jax.Array,  # [N, K]  int32
     *,
-    block_rows: int | None = None,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
     backend: str | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """Served scores for a padded-ELL batch via the best available backend.
 
     Returns ``[N]`` float32 — LR rows are sigmoid probabilities, SVM rows
     raw decision margins (:data:`repro.core.glm.LINKS`).
-    ``block_rows=None`` consults the autotuner cache for this
-    (backend, device, shape-class) before falling back to
-    ``DEFAULT_BLOCK_ROWS``.
     """
     n, kk = values.shape
-    d = w.shape[0]
     info = {"dtype": jnp.result_type(values).name, "sparse": True,
-            "n": n, "d": d, "k": kk}
-    if block_rows is None:
-        b0 = common.resolve_backend("glm_score", backend=backend,
-                                    interpret=interpret, info=info)
-        run = None
-        if tune.timeable(w, values, indices):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "glm_score", task, w, values, indices, backend=b0, **cfg)
-        block_rows = tune.consult("glm_score", b0, info, run) \
-            .get("block_rows", DEFAULT_BLOCK_ROWS)
-    info["block_rows"] = block_rows
+            "n": n, "d": w.shape[0], "k": kk, "block_rows": block_rows}
     return common.dispatch(
         "glm_score", task, w, values, indices, block_rows=block_rows,
-        backend=backend, interpret=interpret, info=info,
+        backend=backend, info=info,
     )

@@ -11,9 +11,8 @@ and be a multiple of 8 or ``n`` itself —
 in the call info, so auto-selection falls through to ``reference``
 (which handles any micro-batch and the ragged tail) instead of dying
 inside the kernel — forcing a Pallas flavor onto such a call raises
-``ValueError``.  When the caller does not pin ``micro_batch``, the
-per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
-before the built-in default.
+``ValueError``.  A caller that pins no ``micro_batch`` gets
+``DEFAULT_MICRO_BATCH``.
 """
 from __future__ import annotations
 
@@ -22,11 +21,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.glm_sgd import kernel as K
 from repro.kernels.glm_sgd import ref as R
 
-#: built-in micro-batch when neither the caller nor the tuner pins one
+#: micro-batch when the caller pins none
 DEFAULT_MICRO_BATCH = 8
 
 
@@ -84,29 +83,14 @@ def glm_sgd_epoch(
     y: jax.Array,   # [N]
     *,
     step: float,
-    micro_batch: int | None = None,
+    micro_batch: int = DEFAULT_MICRO_BATCH,
     backend: str | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
-    """One mini-batch SGD epoch via the best available backend.
-
-    ``micro_batch=None`` consults the autotuner cache for this
-    (backend, device, shape-class) before falling back to
-    ``DEFAULT_MICRO_BATCH``.
-    """
+    """One mini-batch SGD epoch via the best available backend."""
     n, d = X.shape
-    info = {"dtype": jnp.result_type(X).name, "n": n, "d": d}
-    if micro_batch is None:
-        b0 = common.resolve_backend("glm_sgd", backend=backend,
-                                    interpret=interpret, info=info)
-        run = None
-        if tune.timeable(w, X, y):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "glm_sgd", task, w, X, y, step=step, backend=b0, **cfg)
-        micro_batch = tune.consult("glm_sgd", b0, info, run) \
-            .get("micro_batch", DEFAULT_MICRO_BATCH)
-    info["micro_batch"] = micro_batch
+    info = {"dtype": jnp.result_type(X).name, "n": n, "d": d,
+            "micro_batch": micro_batch}
     return common.dispatch(
         "glm_sgd", task, w, X, y, step=step, micro_batch=micro_batch,
-        backend=backend, interpret=interpret, info=info,
+        backend=backend, info=info,
     )

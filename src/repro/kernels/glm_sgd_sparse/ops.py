@@ -11,9 +11,8 @@ the one-hot VMEM budget (:func:`repro.kernels.common.onehot_fits`; the
 one-hot spans the full padded feature axis because the model never
 leaves VMEM).  Forcing a Pallas flavor past either raises
 ``ValueError``; rows too wide for the one-hot train on the CSR path
-(``glm_sgd_csr``).  When the caller does not pin ``micro_batch``, the
-per-device autotuner cache (:mod:`repro.kernels.tune`) is consulted
-before the built-in default.
+(``glm_sgd_csr``).  A caller that pins no ``micro_batch`` gets
+``DEFAULT_MICRO_BATCH``.
 """
 from __future__ import annotations
 
@@ -22,11 +21,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.glm_sgd_sparse import kernel as K
 from repro.kernels.glm_sgd_sparse import ref as R
 
-#: built-in micro-batch when neither the caller nor the tuner pins one
+#: micro-batch when the caller pins none
 DEFAULT_MICRO_BATCH = 8
 
 
@@ -103,33 +102,14 @@ def ell_sgd_epoch(
     y: jax.Array,        # [N]
     *,
     step: float,
-    micro_batch: int | None = None,
+    micro_batch: int = DEFAULT_MICRO_BATCH,
     backend: str | None = None,
-    interpret: bool | None = None,
 ) -> jax.Array:
-    """One mini-batch SGD epoch on ELL data via the best available backend.
-
-    ``micro_batch=None`` consults the autotuner cache for this
-    (backend, device, shape-class) before falling back to
-    ``DEFAULT_MICRO_BATCH``.
-    """
+    """One mini-batch SGD epoch on ELL data via the best available backend."""
     n, kk = values.shape
-    d = w.shape[0]
     info = {"dtype": jnp.result_type(values).name, "sparse": True,
-            "n": n, "d": d, "k": kk}
-    if micro_batch is None:
-        b0 = common.resolve_backend("glm_sgd_sparse", backend=backend,
-                                    interpret=interpret, info=info)
-        run = None
-        if tune.timeable(w, values, indices, y):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "glm_sgd_sparse", task, w, values, indices, y, step=step,
-                backend=b0, **cfg)
-        micro_batch = tune.consult("glm_sgd_sparse", b0, info, run) \
-            .get("micro_batch", DEFAULT_MICRO_BATCH)
-    info["micro_batch"] = micro_batch
+            "n": n, "d": w.shape[0], "k": kk, "micro_batch": micro_batch}
     return common.dispatch(
         "glm_sgd_sparse", task, w, values, indices, y, step=step,
-        micro_batch=micro_batch, backend=backend, interpret=interpret,
-        info=info,
+        micro_batch=micro_batch, backend=backend, info=info,
     )

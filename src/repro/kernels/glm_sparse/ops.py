@@ -18,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import common, tune
+from repro.kernels import common
 from repro.kernels.glm_sparse import kernel as K
 from repro.kernels.glm_sparse import ref as R
 
@@ -99,46 +99,17 @@ def ell_glm_grad(
     indices: jax.Array,  # [N, K] int32
     y: jax.Array,        # [N]
     *,
-    block_rows: int | None = None,
-    d_block: int | None = None,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    d_block: int = DEFAULT_D_BLOCK,
     backend: str | None = None,
-    interpret: bool | None = None,
-    force_path: str | None = None,   # legacy: "pallas" | "xla" | None (auto)
 ) -> jax.Array:
-    """ELL sparse GLM gradient via the best available backend.
-
-    Unpinned ``block_rows``/``d_block`` consult the autotuner cache
-    (:mod:`repro.kernels.tune`); with no cached winner the historical
-    defaults (``DEFAULT_BLOCK_ROWS``, ``DEFAULT_D_BLOCK``) apply.
-    """
+    """ELL sparse GLM gradient via the best available backend."""
     n, kk = values.shape
-    d = w.shape[0]
-    if force_path == "xla":
-        backend = backend or common.REFERENCE
-    elif force_path == "pallas" and backend is None:
-        # legacy forcing bypassed the budget; interpret= picks the flavor
-        use_interp = (not common.on_tpu()) if interpret is None else interpret
-        backend = common.PALLAS_INTERPRET if use_interp else common.PALLAS_TPU
-    elif backend is None and interpret is not None and pallas_path_ok(d, kk):
-        # legacy interpret= chose the Pallas mode but never overrode the
-        # budget: over-budget problems still take the reference path
-        backend = common.PALLAS_INTERPRET if interpret else common.PALLAS_TPU
     info = {"dtype": jnp.result_type(values).name, "sparse": True,
-            "n": n, "d": d, "k": kk}
-    b = common.resolve_backend("glm_sparse", backend=backend, info=info)
-    if block_rows is None and d_block is None:
-        run = None
-        if tune.timeable(w, values, indices, y):
-            run = lambda **cfg: common.dispatch(  # noqa: E731
-                "glm_sparse", task, w, values, indices, y, backend=b, **cfg)
-        cfg = tune.consult("glm_sparse", b, info, run)
-        block_rows = cfg.get("block_rows")
-        d_block = cfg.get("d_block")
+            "n": n, "d": w.shape[0], "k": kk}
     return common.dispatch(
-        "glm_sparse", task, w, values, indices, y,
-        block_rows=DEFAULT_BLOCK_ROWS if block_rows is None else block_rows,
-        d_block=DEFAULT_D_BLOCK if d_block is None else d_block,
-        backend=b, info=info,
+        "glm_sparse", task, w, values, indices, y, block_rows=block_rows,
+        d_block=d_block, backend=backend, info=info,
     )
 
 

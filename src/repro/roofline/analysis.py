@@ -36,7 +36,7 @@ import math
 from pathlib import Path
 
 #: Per-chip peaks keyed by normalized ``device_kind``
-#: (:func:`repro.kernels.tune.device_kind`).  TPU v5e: Google Cloud
+#: (:func:`repro.kernels.common.device_kind`).  TPU v5e: Google Cloud
 #: documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
 #: 1,600 Gbit/s of interconnect (four links of 50 GB/s; 45 GB/s is the
 #: effective figure used here).

@@ -14,9 +14,10 @@ probabilities, SVM margins) built from three pieces —
   grids);
 * **the fused scoring kernel** — ``kernels/glm_score``: one launch per
   batch, model pinned in VMEM, ELL gather as a one-hot product, the
-  task link (LR sigmoid / SVM identity) fused in.  Dispatch goes
-  through the standard three-backend registry, so the engine runs
-  anywhere the conformance suite does;
+  task link (LR sigmoid / SVM identity) fused in.  The engine resolves
+  its flavor once, at construction, through the standard three-backend
+  registry (every launch has the same shape), so it runs anywhere the
+  conformance suite does;
 * **atomic snapshot hot-swap** — the model is an immutable
   :class:`ModelSnapshot`; ``swap_model`` publishes a new snapshot in a
   single reference assignment, and a flush reads the reference exactly
@@ -46,8 +47,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.glm import LINKS
-from repro.kernels.glm_score import glm_score
+from repro.kernels import common
+from repro.kernels.glm_score.ops import DEFAULT_BLOCK_ROWS
 from repro.obs import metrics, trace
+
+
+def glm_score(task: str, w, values, indices, *, flavor: Callable):
+    """One scoring launch on ``flavor``, the ``glm_score`` implementation
+    an engine resolved.  A module-level name, so that a fault or a
+    reference can take the kernel's place (``chipbench/faults.py``)."""
+    return flavor(task, w, values, indices)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,9 +114,14 @@ class GLMScoreEngine:
     flush_deadline_s:
         A non-full batch is flushed once its *oldest* request has waited
         this long (``maybe_flush``); ``flush`` ignores the deadline.
-    backend / block_rows:
-        Forwarded to the ``glm_score`` dispatch (None = auto backend,
-        autotuner-consulted row tile).
+    backend:
+        The ``glm_score`` backend (None = the registry's order: the
+        ``REPRO_KERNEL_BACKEND`` environment variable, else auto by
+        capabilities).  Resolved once, here, for the engine's one launch
+        shape ``[max_batch, ell_width]`` over ``d`` features at the
+        family's ``DEFAULT_BLOCK_ROWS``; ``swap_model`` keeps ``d`` and
+        the task is a static argument, so the choice holds for the
+        engine's life.
     clock:
         Injectable monotonic clock (tests pin deadlines without
         sleeping).
@@ -125,7 +139,7 @@ class GLMScoreEngine:
     def __init__(self, task: str, w, *, ell_width: int,
                  max_batch: int = 32, queue_depth: int = 256,
                  flush_deadline_s: float = 0.005,
-                 backend: str | None = None, block_rows: int | None = None,
+                 backend: str | None = None,
                  clock: Callable[[], float] = time.monotonic,
                  fault_stall_s: float = 0.0):
         if max_batch < 1:
@@ -140,8 +154,6 @@ class GLMScoreEngine:
         self.flush_deadline_s = flush_deadline_s
         if fault_stall_s < 0:
             raise ValueError(f"fault_stall_s must be >= 0: {fault_stall_s}")
-        self.backend = backend
-        self.block_rows = block_rows
         self.fault_stall_s = fault_stall_s
         self.monitor = None
         trace.count_compiles()   # a compile while serving is a stall
@@ -151,6 +163,12 @@ class GLMScoreEngine:
         self._queue: deque = deque()
         self._model = ModelSnapshot(
             task, jnp.asarray(w, jnp.float32).reshape(-1), version=0)
+        info = {"dtype": "float32", "sparse": True, "n": max_batch,
+                "d": self._model.w.shape[0], "k": ell_width,
+                "block_rows": DEFAULT_BLOCK_ROWS}
+        self.backend = common.resolve_backend("glm_score", backend=backend,
+                                              info=info)
+        self._flavor = common.implementation("glm_score", self.backend)
 
     # -- model hot-swap ------------------------------------------------------
 
@@ -276,8 +294,7 @@ class GLMScoreEngine:
                 vals, idx = jnp.asarray(vals), jnp.asarray(idx)
             with trace.span("serve.dispatch"):
                 scores = glm_score(snap.task, snap.w, vals, idx,
-                                   block_rows=self.block_rows,
-                                   backend=self.backend)
+                                   flavor=self._flavor)
             with trace.span("serve.wait"):
                 scores = np.asarray(jax.block_until_ready(scores), np.float32)
             with trace.span("serve.respond"):

@@ -120,7 +120,7 @@ class TrialCache:
     @property
     def device_kind(self) -> str:
         if self._device_kind is None:   # lazily: touching JAX is not free
-            from repro.kernels.tune import device_kind
+            from repro.kernels.common import device_kind
             self._device_kind = device_kind()
         return self._device_kind
 

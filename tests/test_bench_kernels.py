@@ -145,11 +145,11 @@ def test_producer_trajectory_and_byte_reproducibility(tmp_path, monkeypatch):
         assert e["pallas_match"] is True  # interpret flavor checked on CPU
         assert e["roofline"]["bound"] in ("compute", "memory")
         assert {"host", "device_kind", "backend", "config"} <= set(e)
-    # tuned + bf16 variants present for every family
+    # default + bf16 variants present for every family
     variants = {(e["kernel"], e["dtype"], e["variant"])
                 for e in data["entries"].values()}
     for k in TINY_SHAPES:
-        assert (k, "float32", "tuned") in variants
+        assert (k, "float32", "default") in variants
         assert (k, "bfloat16", "default") in variants
     # cold run: committed file absent -> no baselines, gate clean
     assert all(r["baseline_wall_s"] is None for r in rows)

@@ -7,6 +7,7 @@ losses and both dense and sparse data.  Future kernel PRs must keep this
 suite green — it is the executable contract of DESIGN.md §3.
 """
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -21,15 +22,22 @@ from repro.kernels.flash_attn.ref import attention_ref
 from repro.kernels.glm_grad import glm_grad
 from repro.kernels.glm_grad.ref import glm_grad_ref
 from repro.kernels.glm_sgd import glm_sgd_epoch
+from repro.kernels.glm_sgd.ops import DEFAULT_MICRO_BATCH as SGD_MB
 from repro.kernels.glm_sgd.ref import glm_sgd_epoch_ref
 from repro.kernels.glm_sgd_csr import csr_margins, csr_sgd_epoch
 from repro.kernels.glm_sgd_csr.kernel import CHUNK
 from repro.kernels.glm_sgd_csr.ref import csr_margins_ref, csr_sgd_epoch_ref
 from repro.kernels.glm_sgd_sparse import ell_sgd_epoch
+from repro.kernels.glm_sgd_sparse.ops import (
+    DEFAULT_MICRO_BATCH as ELL_SGD_MB,
+)
 from repro.kernels.glm_sgd_sparse.ref import ell_sgd_epoch_ref
 from repro.kernels.glm_score import glm_score
+from repro.kernels.glm_score.ops import DEFAULT_BLOCK_ROWS as SCORE_ROWS
 from repro.kernels.glm_score.ref import glm_score_ref
 from repro.kernels.glm_sparse import ell_glm_grad, ell_margins
+from repro.kernels.glm_sparse.ops import DEFAULT_BLOCK_ROWS as SPARSE_ROWS
+from repro.kernels.glm_sparse.ops import DEFAULT_D_BLOCK as SPARSE_D_BLOCK
 from repro.kernels.glm_sparse.ref import ell_glm_grad_ref, ell_margins_ref
 
 FAMILIES = ("flash_attn", "glm_grad", "glm_score", "glm_sgd",
@@ -62,16 +70,27 @@ def test_host_availability_excludes_pallas_tpu_off_tpu():
         assert (common.PALLAS_TPU in avail) == common.on_tpu()
 
 
+def test_device_kind_raises_without_a_backend(monkeypatch):
+    """No silent "unknown" kind: a record keyed by it must name real
+    hardware."""
+    def boom():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="no backend"):
+        common.device_kind()
+
+
 def test_resolve_backend_precedence(monkeypatch):
     monkeypatch.setenv(common.ENV_BACKEND, common.REFERENCE)
     # env var overrides auto ...
     assert common.resolve_backend("glm_grad") == common.REFERENCE
-    # ... but explicit call-site forcing beats the env var, whether via
-    # backend= or the legacy interpret= flag
+    # ... but call-site forcing beats the env var
     assert (common.resolve_backend("glm_grad", backend=common.PALLAS_INTERPRET)
             == common.PALLAS_INTERPRET)
-    assert (common.resolve_backend("glm_grad", interpret=True)
-            == common.PALLAS_INTERPRET)
+    if not common.on_tpu():   # ... even where the forced flavor cannot run
+        with pytest.raises(RuntimeError, match="needs a TPU host"):
+            common.resolve_backend("glm_grad", backend=common.PALLAS_TPU)
 
 
 def test_resolve_backend_env_override_applies_to_calls(monkeypatch, glm_data):
@@ -80,14 +99,6 @@ def test_resolve_backend_env_override_applies_to_calls(monkeypatch, glm_data):
     out = glm_grad("lr", w, X, y)
     np.testing.assert_allclose(out, glm_grad_ref("lr", w, X, y),
                                rtol=1e-6, atol=1e-6)
-
-
-def test_resolve_backend_legacy_interpret_flag():
-    assert (common.resolve_backend("glm_grad", interpret=True)
-            == common.PALLAS_INTERPRET)
-    if not common.on_tpu():
-        with pytest.raises(RuntimeError, match="needs a TPU host"):
-            common.resolve_backend("glm_grad", interpret=False)
 
 
 def test_resolve_backend_rejects_unknown():
@@ -138,25 +149,6 @@ def test_caps_route_huge_sparse_problem_to_reference():
             != common.REFERENCE)
 
 
-def test_glm_sparse_legacy_interpret_respects_budget(monkeypatch, ell_data):
-    """interpret= picks the Pallas flavor in budget, but never forces the
-    one-hot kernel onto problems the VMEM/FLOP budget excludes."""
-    seen = []
-    real = common.dispatch
-
-    def spy(kernel, *a, **kw):
-        seen.append(kw.get("backend"))
-        return real(kernel, *a, **kw)
-
-    monkeypatch.setattr(common, "dispatch", spy)
-    values, indices, y, w = ell_data(32, 256, 4)
-    ell_glm_grad("lr", w, values, indices, y, interpret=True, d_block=128)
-    assert seen[-1] == common.PALLAS_INTERPRET
-    big_w = jnp.zeros(40_000)  # d > _MAX_D_PALLAS
-    ell_glm_grad("lr", big_w, values, indices, y, interpret=True)
-    assert seen[-1] == common.REFERENCE  # caps route the call to reference
-
-
 def test_caps_route_odd_head_dim_to_reference(attn_data):
     q, k, v = attn_data(1, 2, 2, 16, 16, 12)  # hd=12: not sublane-aligned
     assert (common.resolve_backend("flash_attn",
@@ -165,6 +157,54 @@ def test_caps_route_odd_head_dim_to_reference(attn_data):
     out = flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, attention_ref(q, k, v, causal=True),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Tiles: a call that pins none runs at its family's built-in one
+# ---------------------------------------------------------------------------
+
+TILED = ("flash_attn", "glm_grad", "glm_score", "glm_sgd", "glm_sgd_sparse",
+         "glm_sparse")
+
+
+def _tile_calls(family, backend, glm_data, ell_data, attn_data):
+    """One family's call with the tile left to it, and the tile it must
+    then take."""
+    if family == "flash_attn":
+        q, k, v = attn_data(1, 2, 1, 16, 24, 8)
+        return (functools.partial(flash_attention, q, k, v, causal=True,
+                                  backend=backend),
+                {"block_q": common.pick_block(16, 128, common.SUBLANE),
+                 "block_k": common.pick_block(24, 128, common.SUBLANE)})
+    if family in ("glm_grad", "glm_sgd"):
+        X, y, w = glm_data(40, 12)
+        if family == "glm_grad":   # N padded to sublanes, at most 512 rows
+            return (functools.partial(glm_grad, "lr", w, X, y,
+                                      backend=backend), {"block_rows": 40})
+        return (functools.partial(glm_sgd_epoch, "lr", w, X, y, step=0.05,
+                                  backend=backend), {"micro_batch": SGD_MB})
+    values, indices, y, w = ell_data(24, 64, 4)
+    if family == "glm_score":
+        return (functools.partial(glm_score, "lr", w, values, indices,
+                                  backend=backend), {"block_rows": SCORE_ROWS})
+    if family == "glm_sgd_sparse":
+        return (functools.partial(ell_sgd_epoch, "lr", w, values, indices, y,
+                                  step=0.05, backend=backend),
+                {"micro_batch": ELL_SGD_MB})
+    return (functools.partial(ell_glm_grad, "lr", w, values, indices, y,
+                              backend=backend),
+            {"block_rows": SPARSE_ROWS, "d_block": SPARSE_D_BLOCK})
+
+
+@pytest.mark.parametrize(
+    "family,backend",
+    [(f, b) for f in TILED for b in common.available_backends(f)])
+def test_unpinned_tile_is_the_family_default(family, backend, glm_data,
+                                             ell_data, attn_data):
+    """Bit for bit: leaving the tile out is pinning the built-in one."""
+    call, tile = _tile_calls(family, backend, glm_data, ell_data, attn_data)
+    np.testing.assert_array_equal(np.asarray(call()),
+                                  np.asarray(call(**tile)))
 
 
 # ---------------------------------------------------------------------------

@@ -79,7 +79,8 @@ def test_glm_sparse_kernel(task, n, d, k, rng):
     w = jnp.asarray(rng.normal(0, 0.1, d).astype(np.float32))
     ref = ell_glm_grad_ref(task, w, ds.ell.values, ds.ell.indices, y)
     out = ell_glm_grad(task, w, ds.ell.values, ds.ell.indices, y,
-                       block_rows=8, d_block=256, force_path="pallas")
+                       block_rows=8, d_block=256,
+                       backend=common.available_backends("glm_sparse")[0])
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=2e-3)
 
 

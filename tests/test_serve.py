@@ -333,6 +333,63 @@ def test_score_engine_swap_model_atomic_versioning():
         eng.swap_model(np.zeros(23, np.float32))
 
 
+def _moved(before: dict, prefix: str) -> dict:
+    from repro.obs import metrics
+
+    now = metrics.snapshot()["counters"]
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if k.startswith(prefix) and v != before.get(k, 0)}
+
+
+def test_score_engine_resolves_its_kernel_once():
+    """One backend resolution per engine: at construction, none a flush."""
+    from repro.obs import metrics
+
+    before = metrics.snapshot()["counters"]
+    eng, w = _score_engine(max_batch=4, queue_depth=8)
+    for rid in range(10):
+        assert eng.try_admit(_req(rid))
+        (resp,) = eng.flush()
+        assert resp.score == pytest.approx(_oracle("lr", w, _req(rid)),
+                                           abs=1e-4)
+    assert sum(_moved(before, "kernel.backend.glm_score.").values()) == 1
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas-interpret"])
+def test_score_engine_forced_backend_holds_across_flushes_and_swaps(backend):
+    from repro.obs import metrics
+
+    before = metrics.snapshot()["counters"]
+    eng, w = _score_engine("svm", max_batch=4, queue_depth=8,
+                           backend=backend)
+    w2 = -2 * w
+    for rid in range(6):
+        if rid == 3:
+            eng.swap_model(w2)
+        assert eng.try_admit(_req(rid))
+        (resp,) = eng.flush()
+        want = _oracle("svm", w if rid < 3 else w2, _req(rid))
+        assert resp.score == pytest.approx(want, abs=1e-4)
+    assert eng.backend == backend
+    assert _moved(before, "kernel.backend.glm_score.") == {
+        f"kernel.backend.glm_score.{backend}": 1}
+
+
+def test_score_engine_over_budget_one_hot_resolves_to_reference():
+    """A model too wide for the one-hot in VMEM: the engine takes the
+    reference flavor when it is built, and scores right with it."""
+    d = 2048   # 8 rows x 2,048 features x 128 lanes x 4 B = 8 MiB > 4 MiB
+    eng, w = _score_engine(d=d, k=3, max_batch=4, queue_depth=8)
+    assert eng.backend == "reference"
+    reqs = [_req(rid, d=d) for rid in range(5)]
+    for r in reqs:
+        assert eng.try_admit(r)
+    out = eng.drain()
+    assert [r.rid for r in out] == [0, 1, 2, 3, 4]
+    for resp, req in zip(out, reqs):
+        assert resp.score == pytest.approx(_oracle("lr", w, req), abs=1e-4)
+
+
 @given(data=st.data())
 @settings(max_examples=20, deadline=None)
 def test_score_engine_admission_properties(data):
